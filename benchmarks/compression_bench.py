@@ -16,13 +16,14 @@ import numpy as np
 def transport_bytes() -> dict:
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.launch.hlo_analysis import analyze
+    from repro.launch.mesh import make_mesh
     from repro.optim.compression import compressed_psum_int8
 
     n = 1 << 20  # 4 MB fp32 gradient shard
-    mesh = jax.make_mesh((jax.device_count(),), ("x",))
+    mesh = make_mesh((jax.device_count(),), ("x",))
 
     def f_fp32(x):
         return jax.lax.pmean(x, "x")
@@ -33,7 +34,7 @@ def transport_bytes() -> dict:
     out = {}
     for name, f in (("fp32_pmean", f_fp32), ("int8_ef", f_int8)):
         sf = shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
-                       check_rep=False)
+                       check_vma=False)
         text = jax.jit(sf).lower(
             jax.ShapeDtypeStruct((n,), jnp.float32)).compile().as_text()
         c = analyze(text)

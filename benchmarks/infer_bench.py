@@ -66,6 +66,7 @@ from repro.core.spike import (num_plane_groups, pack_timesteps,
 from repro.core.spikformer import SpikformerConfig, init as spik_init
 from repro.infer import (ExecutionPlan, MicroBatchEngine, chunk_occupancy,
                          compile as infer_compile)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.kernels import lut_matmul as lut
 from repro.kernels import ops
 from repro.kernels.lut_matmul import sparse_budget
@@ -637,6 +638,7 @@ def main(argv=None):
                          f"(bare --out means {DEFAULT_OUT.name} at the "
                          "repo root)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     # smoke still times 4-batch windows: a 1-batch window measures a single
     # dispatch and its speedup ratio is pure noise, useless even with a

@@ -10,15 +10,15 @@ import time
 import jax
 
 from repro.configs.base import get_config
+from repro.launch.mesh import make_mesh
 from repro.launch.serve import Engine, Request
-from repro.sharding.compat import set_mesh
 
 
 def main():
     cfg = get_config("smollm-360m").reduced(
         n_layers=4, d_model=256, vocab=2048)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
-    with set_mesh(mesh):
+    mesh = make_mesh((1, 1), ("data", "model"))
+    with jax.set_mesh(mesh):
         eng = Engine(cfg, slots=4, cache_len=256, seed=0)
         rng = jax.random.PRNGKey(1)
         t0 = time.time()

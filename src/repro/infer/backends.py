@@ -53,7 +53,7 @@ from ..core import unified
 from ..core.lif import V_TH, tflif
 from ..core.spike import (bitplanes_u8, packed_occupancy, rate_decode,
                           space_to_depth)
-from ..kernels import ops
+from ..kernels import fused, ops
 from ..kernels import lut_matmul as lut
 
 
@@ -261,14 +261,19 @@ class PackedBackend:
         either.
 
         Returns None when the fused kernel does not apply — CPU-oracle
-        sessions, ``fuse_mlp=False``, or no (C,256,N) table planned for fc2
-        — and the caller falls back to the unfused two-layer composition.
+        sessions, ``fuse_mlp=False``, no (C,256,N) table planned for fc2,
+        or a table too large to stay VMEM-resident
+        (``kernels.fused.MAX_TABLE_BYTES``) — and the caller falls back to
+        the unfused two-layer composition. The decision is static (shapes
+        only), made at trace time.
         ``occupancy`` is fc1's input calibration, forwarded to its matmul.
         """
         if not (self.fuse_mlp and ops.use_pallas(self.pallas)):
             return None
         tbl2 = fc2.get("lut")
         if not ops._have_table(tbl2):
+            return None
+        if tbl2.size * tbl2.dtype.itemsize > fused.MAX_TABLE_BYTES:
             return None
         scale1 = fc1.get("scale")
         acc1 = ops.spike_linear(x, self._w(fc1["kernel"], scale1), None,
@@ -363,9 +368,9 @@ class OccupancyRecorder(PackedBackend):
 
 # keyword-only factories: a misspelled option key must raise TypeError,
 # not silently run the default route. Every factory accepts + ignores
-# ``interpret`` — it is the registry's device-gate escape hatch (see
-# ``registry.get_backend``), consumed there, but also forwarded here so a
-# pre-resolved options dict round-trips.
+# ``interpret`` — the registry's CPU-only "run the Pallas interpreter"
+# gate (see ``registry.get_backend``), checked there; the kernels make the
+# same call themselves (``kernels.device.resolve_interpret``).
 registry.register_backend(
     "packed",
     lambda *, pallas=None, fuse_mlp=True, interpret=None:
@@ -386,8 +391,8 @@ registry.register_backend(
 
 # The Pallas-pinned packed backend: the registration path the registry
 # docstring promises, as a real registration. Same PackedBackend class,
-# pallas=True forced — the real kernels on TPU, interpret mode elsewhere
-# (the registry's device gate makes off-TPU use an explicit
+# pallas=True forced — the compiled kernels on TPU, interpret mode on a CPU
+# host (the registry's device gate makes that an explicit
 # ``backend_options={'interpret': True}`` opt-in). Route planning DOES
 # build (C,256,N) tables for it: the Pallas byte-LUT gather kernel and the
 # fused MLP kernel consume them from VMEM.

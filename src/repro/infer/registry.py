@@ -20,9 +20,10 @@ Capabilities:
   ``get_backend`` enforces this against the current JAX platform: asking
   for a TPU-only backend on a CPU host fails up front with the available
   platforms named, instead of tracing kernels that cannot lower. Passing
-  ``interpret=True`` in the options is the explicit escape hatch — every
-  backend here also runs in Pallas interpret/oracle mode, which is exactly
-  how tier-1 exercises the ``packed_pallas`` kernels on CPU.
+  ``interpret=True`` in the options means exactly "run the Pallas kernels
+  under the interpreter, on a CPU host" — how tier-1 exercises the
+  ``packed_pallas`` kernels. On a TPU host it is refused: there the
+  kernels always run compiled (``kernels.device.resolve_interpret``).
 * ``wants_lut_tables`` — whether the route planner should build and cache
   the (C, 256, N) byte-LUT tables into this backend's folded tree, or only
   flag planned layers. ``None`` defers to the backend *instance* (the
@@ -136,26 +137,32 @@ def get_backend(name, **options):
 
     The spec's ``device_kinds`` is enforced here: a backend built for
     hardware this host does not have fails loudly, naming the platforms
-    that ARE available and the ``interpret=True`` escape hatch that runs
-    its kernels under the Pallas interpreter instead (the tier-1 testing
-    mode). The hatch is an explicit opt-in so nobody mistakes interpreted
-    timings for the real thing.
+    that ARE available and the ``interpret=True`` option that runs its
+    kernels under the Pallas interpreter instead (the tier-1 testing
+    mode). The option is an explicit opt-in so nobody mistakes interpreted
+    timings for the real thing, and it is CPU-only: on a TPU host it is
+    refused rather than ignored.
     """
     if not isinstance(name, str):
         return name
     spec = backend_spec(name)
-    if not options.get("interpret"):
-        import jax
-        platform = jax.default_backend()
-        if platform not in spec.device_kinds:
-            available = sorted({d.platform for d in jax.devices()})
-            raise ValueError(
-                f"backend {spec.name!r} targets device kind(s) "
-                f"{sorted(spec.device_kinds)} but the current JAX platform "
-                f"is {platform!r} (available: {available}); pass "
-                "backend_options={'interpret': True} to run its Pallas "
-                "kernels in interpret mode on this host (bit-exact, "
-                "test-speed only)")
+    import jax
+    platform = jax.default_backend()
+    interpret = options.get("interpret")
+    if interpret and platform == "tpu":
+        raise ValueError(
+            f"backend {spec.name!r}: interpret=True selects the Pallas "
+            "interpreter, a CPU test mode; on a TPU the kernels run "
+            "compiled — drop the option")
+    if not interpret and platform not in spec.device_kinds:
+        available = sorted({d.platform for d in jax.devices()})
+        raise ValueError(
+            f"backend {spec.name!r} targets device kind(s) "
+            f"{sorted(spec.device_kinds)} but the current JAX platform "
+            f"is {platform!r} (available: {available}); pass "
+            "backend_options={'interpret': True} to run its Pallas "
+            "kernels in interpret mode on this host (bit-exact, "
+            "test-speed only)")
     return spec.make(**options)
 
 

@@ -17,6 +17,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .device import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -60,7 +62,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 @functools.partial(jax.jit, static_argnames=("scale", "causal", "bq", "bkv",
                                              "interpret"))
 def flash_attention(q, k, v, *, scale: float, causal: bool = True,
-                    bq: int = 128, bkv: int = 128, interpret: bool = True):
+                    bq: int = 128, bkv: int = 128,
+                    interpret: bool | None = None):
     """q: (BH, Nq, Dh); k, v: (BH, Nkv, Dh) -> (BH, Nq, Dh)."""
     bh, nq, dh = q.shape
     nkv = k.shape[1]
@@ -96,6 +99,6 @@ def flash_attention(q, k, v, *, scale: float, causal: bool = True,
             pltpu.VMEM((bq_,), jnp.float32),
             pltpu.VMEM((bq_, dh), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)
     return y[:, :nq, :]

@@ -26,8 +26,11 @@ fused step is bit-exact against the unfused composition on every backend,
 which is what lets the packed_pallas backend enable it by default.
 
 Interpret mode (CPU tier-1) runs the same kernel body under the Pallas
-interpreter; the VMEM-residency claim (whole (C, 256, N) table per grid
-step) is a real-TPU sizing constraint documented in kernels/README.md.
+interpreter. The whole (C, 256, N) table sits in VMEM for every grid step,
+so the kernel only serves tables up to ``MAX_TABLE_BYTES``; the backend
+runs the two-kernel path for larger ones (the published widths' fc2 table
+is 64 MiB in int16 — the route planner already sends fc2 to the unpack
+route there, so the fused step does not apply).
 """
 from __future__ import annotations
 
@@ -35,50 +38,60 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from .device import resolve_interpret, vmem_limit
 from .spike_matmul import gather256
 from .tflif import TAU, V_TH, lif_charge_fire
 from .lut_matmul import K_CHUNK, num_k_chunks
 from ..core.spike import num_plane_groups
 
+# largest fc2 table the fused kernel keeps VMEM-resident (double-buffered)
+MAX_TABLE_BYTES = 4 << 20
+
 
 def _kernel(x_ref, b_ref, vth_ref, tbl_ref, s_ref, o_ref, *, t_steps: int,
             tau: float, acc_dtype):
-    """x_ref: (T, bm, K) fc1 accumulators; b_ref, vth_ref: (K,); tbl_ref:
+    """x_ref: (T, bm, K) fc1 accumulators; b_ref, vth_ref: (1, K); tbl_ref:
     (C, 256, N) fc2 chunk-partial-sum table (VMEM-resident); s_ref:
     (G, bm, K) uint8 packed spikes out; o_ref: (T, bm, N) f32 fc2
     accumulators out. K is pre-padded to C*8 by the wrapper."""
     bias = b_ref[...]
     v_th = vth_ref[...]
     groups = s_ref.shape[0]
-    bm = x_ref.shape[1]
+    bm, k = x_ref.shape[1:]
     c = tbl_ref.shape[0]
-    v = jnp.zeros_like(x_ref[0])
+    # LUT index bytes from the spike bits as one MXU dot: byte c's bit i is
+    # the spike of input 8c+i, i.e. idx = spikes @ P with P[8c+i, c] = 2^i.
+    # Every product and partial sum is an integer <= 255, exact in any
+    # precision — and no (bm, C, 8) relayout, which Mosaic cannot do.
+    row = lax.broadcasted_iota(jnp.int32, (k, c), 0)
+    col = lax.broadcasted_iota(jnp.int32, (k, c), 1)
+    pack = jnp.where((row >> 3) == col, (1 << (row & 7)).astype(jnp.float32),
+                     0.0)
+    v = jnp.zeros((bm, k), jnp.float32)
     for g in range(groups):            # static unroll: T lives in VREGs
-        packed = jnp.zeros((bm, x_ref.shape[2]), jnp.uint8)
+        packed = jnp.zeros((bm, k), jnp.int32)
         for j in range(min(8, t_steps - 8 * g)):
             v, s = lif_charge_fire(v, x_ref[8 * g + j], bias, v_th, tau=tau)
-            su8 = s.astype(jnp.uint8)
-            packed = packed | (su8 << jnp.uint8(j))
-            # LUT index bytes straight from the spike bits: byte c's bit i
-            # is the spike of input 8c+i — the same value plane_indices
-            # computes from packed bytes, no bit transpose needed here
-            sc = su8.reshape(bm, c, K_CHUNK)
-            idx = sc[:, :, 0]
-            for i in range(1, K_CHUNK):
-                idx = idx | (sc[:, :, i] << jnp.uint8(i))
-            y = gather256(tbl_ref[0], idx[:, 0], acc_dtype)
+            packed = packed | (s.astype(jnp.int32) << j)
+            idx = jnp.dot(s.astype(jnp.float32), pack,
+                          preferred_element_type=jnp.float32
+                          ).astype(jnp.int32)              # (bm, C)
+            y = gather256(tbl_ref[0], idx[:, 0:1], acc_dtype)
             for chunk in range(1, c):  # the defined ascending-chunk fold
-                y = y + gather256(tbl_ref[chunk], idx[:, chunk], acc_dtype)
+                y = y + gather256(tbl_ref[chunk], idx[:, chunk:chunk + 1],
+                                  acc_dtype)
             o_ref[8 * g + j] = y.astype(jnp.float32)
-        s_ref[g] = packed
+        s_ref[g] = packed.astype(jnp.uint8)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("tau", "bm", "interpret"))
 def tflif_lut_matmul(x, bias, table, *, v_th=V_TH, tau: float = TAU,
-                     bm: int = 128, interpret: bool = True):
+                     bm: int = 128, interpret: bool | None = None):
     """Fused TFLIF + pack + byte-LUT matmul over a linear pair.
 
     Args:
@@ -116,6 +129,7 @@ def tflif_lut_matmul(x, bias, table, *, v_th=V_TH, tau: float = TAU,
     rp, kp = x.shape[1:]
     acc_dtype = (jnp.int32 if jnp.issubdtype(table.dtype, jnp.integer)
                  else jnp.float32)
+    x_block_bytes = t_steps * bm_ * (kp * 4 + n * 4) + groups * bm_ * kp
 
     spikes, acc = pl.pallas_call(
         functools.partial(_kernel, t_steps=t_steps, tau=tau,
@@ -123,8 +137,8 @@ def tflif_lut_matmul(x, bias, table, *, v_th=V_TH, tau: float = TAU,
         grid=(rp // bm_,),
         in_specs=[
             pl.BlockSpec((t_steps, bm_, kp), lambda i: (0, i, 0)),
-            pl.BlockSpec((kp,), lambda i: (0,)),
-            pl.BlockSpec((kp,), lambda i: (0,)),
+            pl.BlockSpec((1, kp), lambda i: (0, 0)),
+            pl.BlockSpec((1, kp), lambda i: (0, 0)),
             pl.BlockSpec((c, 256, n), lambda i: (0, 0, 0)),
         ],
         out_specs=[
@@ -135,6 +149,9 @@ def tflif_lut_matmul(x, bias, table, *, v_th=V_TH, tau: float = TAU,
             jax.ShapeDtypeStruct((groups, rp, kp), jnp.uint8),
             jax.ShapeDtypeStruct((t_steps, rp, n), jnp.float32),
         ],
-        interpret=interpret,
-    )(x.astype(jnp.float32), bias, v_th, table)
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit(2 * (table.size * table.dtype.itemsize
+                                             + x_block_bytes))),
+        interpret=resolve_interpret(interpret),
+    )(x.astype(jnp.float32), bias[None], v_th[None], table)
     return spikes[:, :r, :k], acc[:, :r, :]

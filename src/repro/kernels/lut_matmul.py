@@ -246,7 +246,7 @@ def lut_matmul_sparse(idx, table, *, max_chunks: int,
 
 
 def lut_matmul_pallas(idx, table, *, bm: int = 128, bn: int = 128,
-                      bc: int = 32, interpret: bool = True):
+                      bc: int = 128, interpret: bool | None = None):
     """Pallas byte-LUT matmul: (..., C) index bytes x (C, 256, N) table ->
     (..., N) f32, same contract as ``lut_matmul`` but executed by the
     grouped-grid Pallas kernel (``spike_matmul.lut_gather_matmul``) with

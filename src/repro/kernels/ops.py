@@ -1,9 +1,10 @@
 """Jit'd dispatch wrappers for the Pallas kernels.
 
-On a real TPU the Pallas kernels run compiled; on CPU (this container) they
-run in interpret mode for correctness, and the pure-XLA reference path is used
-wherever wall-time matters (training/benchmarks). ``use_pallas()`` picks the
-default; every wrapper takes an explicit override.
+On a TPU the Pallas kernels run compiled; elsewhere they run under the Pallas
+interpreter for correctness (``kernels.device.resolve_interpret`` makes that
+call, once, for every kernel), and the pure-XLA reference path is used
+wherever wall-time matters off-TPU. ``use_pallas()`` picks the default; every
+wrapper takes an explicit override.
 
 Plane-group convention (the arbitrary-T packed representation): a T-timestep
 binary activation is stored as ``G = ceil(T/8)`` uint8 *plane groups* with a
@@ -18,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from . import ref
+from .device import on_tpu
 from . import lut_matmul as lut
 from .lut_matmul import (  # noqa: F401  (re-export: the dispatch heuristic)
     RouteConstants, choose_pallas_route, choose_route)
@@ -95,10 +97,6 @@ def _resolve_route_pallas(route, table, *, m, k, n, g, t, weights_are_int,
     return "lut" if route == "lut_sparse" else route
 
 
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def use_pallas(override: bool | None = None) -> bool:
     if override is not None:
         return override
@@ -125,8 +123,7 @@ def spike_matmul(x_packed, w, *, mode: str = "per_plane",
       (M, N) f32 for mode="shift_sum".
     """
     if use_pallas(pallas):
-        return _spike_matmul_pallas(x_packed, w, mode=mode,
-                                    interpret=not on_tpu(), **blocks)
+        return _spike_matmul_pallas(x_packed, w, mode=mode, **blocks)
     return ref.spike_matmul_ref(x_packed, w, mode=mode)
 
 
@@ -148,8 +145,7 @@ def tflif_fused(x, bias=None, *, tau: float = 2.0, v_th=1.0,
       8g + j. Membrane state is carried across group boundaries.
     """
     if use_pallas(pallas):
-        return _tflif_pallas(x, bias, tau=tau, v_th=v_th,
-                             interpret=not on_tpu())
+        return _tflif_pallas(x, bias, tau=tau, v_th=v_th)
     return ref.tflif_ref(x, bias, tau=tau, v_th=v_th)
 
 
@@ -161,8 +157,7 @@ def stdp_attention(q, k, v, *, scale: float, pallas: bool | None = None,
     callers fold T into BH). Returns (BH, N, Dh) f32 exact accumulators.
     """
     if use_pallas(pallas):
-        return _stdp_pallas(q, k, v, scale=scale, interpret=not on_tpu(),
-                            **blocks)
+        return _stdp_pallas(q, k, v, scale=scale, **blocks)
     return ref.stdp_attention_ref(q, k, v, scale=scale)
 
 
@@ -173,8 +168,7 @@ def flash_attention(q, k, v, *, scale: float, causal: bool = True,
     q: (BH, Nq, Dh); k, v: (BH, Nkv, Dh). Returns (BH, Nq, Dh) f32.
     """
     if use_pallas(pallas):
-        return _flash_pallas(q, k, v, scale=scale, causal=causal,
-                             interpret=not on_tpu(), **blocks)
+        return _flash_pallas(q, k, v, scale=scale, causal=causal, **blocks)
     return ref.flash_attention_ref(q, k, v, scale=scale, causal=causal)
 
 
@@ -249,11 +243,9 @@ def spike_linear(x_packed, w, bias=None, *, t: int,
         if resolved == "lut":
             tbl = table if _have_table(table) else lut.build_lut(w)
             idx = lut.plane_indices(x2)[:t]                # (t, M, C)
-            per = lut.lut_matmul_pallas(idx, tbl,
-                                        interpret=not on_tpu())
+            per = lut.lut_matmul_pallas(idx, tbl)
         else:
-            per8 = _spike_matmul_pallas(x2, w, mode="per_plane",
-                                        interpret=not on_tpu(), **blocks)
+            per8 = _spike_matmul_pallas(x2, w, mode="per_plane", **blocks)
             per = per8.reshape(g * 8, m, n)[:t]            # (t, M, N)
         if bias is not None:
             per = per + bias.astype(per.dtype)
@@ -318,12 +310,10 @@ def sssc_linear(x_u8, w, bias=None, *, pallas: bool | None = None,
         if resolved == "lut":
             tbl = table if _have_table(table) else lut.build_lut(w)
             idx = lut.plane_indices(x2[None])              # (8, M, C)
-            per = lut.lut_matmul_pallas(idx, tbl,
-                                        interpret=not on_tpu())
+            per = lut.lut_matmul_pallas(idx, tbl)
             y = lut.shift_sum_fold(per)                    # (M, N)
         else:
-            y = _spike_matmul_pallas(x2, w, mode="shift_sum",
-                                     interpret=not on_tpu(), **blocks)
+            y = _spike_matmul_pallas(x2, w, mode="shift_sum", **blocks)
         if bias is not None:
             y = y + bias.astype(y.dtype)
         return y.reshape((*lead, n))
@@ -432,8 +422,7 @@ def tflif_lut(acc, bias=None, *, table, v_th=1.0, t: int | None = None,
         b = None if bias is None else jnp.broadcast_to(
             jnp.asarray(bias, jnp.float32), (k,))
         vth = jnp.broadcast_to(jnp.asarray(v_th, jnp.float32), (k,))
-        spikes, acc2 = _tflif_lut_pallas(x2, b, table, v_th=vth, tau=tau,
-                                         interpret=not on_tpu())
+        spikes, acc2 = _tflif_lut_pallas(x2, b, table, v_th=vth, tau=tau)
         return (spikes.reshape(spikes.shape[0], *lead, k),
                 acc2.reshape(t, *lead, n))
     spikes = tflif_pack(acc, bias, tau=tau, v_th=v_th, pallas=pallas)

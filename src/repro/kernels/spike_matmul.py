@@ -32,6 +32,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .device import resolve_interpret, vmem_limit
+
 
 def _kernel(x_ref, w_ref, o_ref, acc_ref, *, mode: str, nk: int,
             k_dim: int = 2):
@@ -46,12 +48,15 @@ def _kernel(x_ref, w_ref, o_ref, acc_ref, *, mode: str, nk: int,
     x = x_ref[...]
     if x.ndim == 3:                     # grouped grid: squeeze the g block dim
         x = x[0]
+    # Mosaic has no 8-bit vector arithmetic: widen the bytes before any
+    # shift or float cast (the HBM->VMEM stream stays 1 byte per 8 planes)
+    x = x.astype(jnp.int32)
     w = w_ref[...].astype(jnp.float32)
     bm, bk = x.shape
     if mode == "per_plane":
-        # (bm, bk) uint8 -> (8, bm, bk) bits -> (8*bm, bk) rows -> one MXU dot
-        bits = (x[None, :, :] >> jnp.arange(8, dtype=jnp.uint8)[:, None, None]
-                ) & jnp.uint8(1)
+        # (bm, bk) bytes -> (8, bm, bk) bits -> (8*bm, bk) rows -> one MXU dot
+        shifts = lax.broadcasted_iota(jnp.int32, (8, 1, 1), 0)
+        bits = (x[None, :, :] >> shifts) & 1
         planes = bits.reshape(8 * bm, bk).astype(jnp.float32)
         part = jnp.dot(planes, w, preferred_element_type=jnp.float32)
         acc_ref[...] += part.reshape(8, bm, w.shape[-1])
@@ -68,7 +73,7 @@ def _kernel(x_ref, w_ref, o_ref, acc_ref, *, mode: str, nk: int,
 @functools.partial(jax.jit, static_argnames=("mode", "bm", "bn", "bk", "interpret"))
 def spike_matmul(x_packed, w, *, mode: str = "per_plane",
                  bm: int = 128, bn: int = 128, bk: int = 256,
-                 interpret: bool = True):
+                 interpret: bool | None = None):
     """x_packed: (M, K) uint8 (bit p of [m,k] = plane p's spike) or, for
     mode="per_plane" only, (G, M, K) plane groups; w: (K, N).
 
@@ -117,7 +122,7 @@ def spike_matmul(x_packed, w, *, mode: str = "per_plane",
         out_specs=out_spec,
         out_shape=out_shape,
         scratch_shapes=[acc],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x_packed, w)
 
     if mode == "per_plane":
@@ -127,7 +132,7 @@ def spike_matmul(x_packed, w, *, mode: str = "per_plane",
 
 def gather256(tbl_c, idx_col, acc_dtype):
     """Gather one LUT chunk inside a kernel: ``tbl_c`` (256, bn) partial
-    sums, ``idx_col`` (bm,) uint8 index bytes -> (bm, bn) gathered rows.
+    sums, ``idx_col`` (bm, 1) int32 index bytes -> (bm, bn) gathered rows.
 
     Implemented as a one-hot matmul rather than a dynamic gather — the MXU
     has no gather unit, but a (bm, 256) one-hot against the VMEM-resident
@@ -136,13 +141,20 @@ def gather256(tbl_c, idx_col, acc_dtype):
     zeros (0 * v and 1 * v are both exact in IEEE), so the sum equals the
     selected table entry bit for bit regardless of how the hardware
     associates it (up to the sign of a zero, which ``==`` ignores).
-    Integer tables accumulate in int32, exactly as the CPU gather.
+
+    The dot runs in float32 at ``Precision.HIGHEST`` — the MXU has no
+    int32 matmul, and HIGHEST splits each f32 table entry into bf16 pieces
+    that sum back exactly, so the one-hot select stays exact on the TPU.
+    Integer tables (entries bounded by 8 * 127) come back as exact
+    integer-valued floats and accumulate in int32, as the CPU gather does.
     """
     iota = lax.broadcasted_iota(jnp.int32, (idx_col.shape[0], 256), 1)
-    onehot = (idx_col.astype(jnp.int32)[:, None] == iota).astype(acc_dtype)
-    return lax.dot_general(onehot, tbl_c.astype(acc_dtype),
-                           (((1,), (0,)), ((), ())),
-                           preferred_element_type=acc_dtype)
+    onehot = (idx_col == iota).astype(jnp.float32)
+    y = lax.dot_general(onehot, tbl_c.astype(jnp.float32),
+                        (((1,), (0,)), ((), ())),
+                        precision=lax.Precision.HIGHEST,
+                        preferred_element_type=jnp.float32)
+    return y.astype(acc_dtype)
 
 
 def _lut_kernel(idx_ref, tbl_ref, o_ref, acc_ref, *, nc: int, bc: int):
@@ -158,10 +170,10 @@ def _lut_kernel(idx_ref, tbl_ref, o_ref, acc_ref, *, nc: int, bc: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    idx = idx_ref[0]                    # (bm, bc)
+    idx = idx_ref[0].astype(jnp.int32)  # (bm, bc); no 8-bit vector math
     acc = acc_ref[...]
     for cc in range(bc):                # static unroll: the defined fold
-        acc = acc + gather256(tbl_ref[cc], idx[:, cc], acc.dtype)
+        acc = acc + gather256(tbl_ref[cc], idx[:, cc:cc + 1], acc.dtype)
     acc_ref[...] = acc
 
     @pl.when(c_step == nc - 1)
@@ -171,7 +183,7 @@ def _lut_kernel(idx_ref, tbl_ref, o_ref, acc_ref, *, nc: int, bc: int):
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bc", "interpret"))
 def lut_gather_matmul(idx, table, *, bm: int = 128, bn: int = 128,
-                      bc: int = 32, interpret: bool = True):
+                      bc: int = 128, interpret: bool | None = None):
     """Pallas byte-LUT matmul: (P, M, C) uint8 per-plane index bytes x
     (C, 256, N) chunk-partial-sum table -> (P, M, N) f32 accumulators.
 
@@ -185,6 +197,12 @@ def lut_gather_matmul(idx, table, *, bm: int = 128, bn: int = 128,
     inside each tile), with int32 accumulation for int16 tables, so the
     result is bit-exact against the CPU gather route and its
     ``lut_matmul_planes`` float oracle.
+
+    The chunk axis is the index block's lane dim, so on a TPU a chunk tile
+    is either every chunk (C <= bc) or a multiple of 128 chunks — the
+    default ``bc`` is one lane width; smaller tiles are for interpret-mode
+    tiling tests. The double-buffered table tile sets the VMEM limit the
+    kernel asks for.
 
     Padding: M pads with zero index bytes (they gather the exact-zero
     ``table[c, 0, :]`` entry), N pads the table with zero columns, C pads
@@ -205,6 +223,7 @@ def lut_gather_matmul(idx, table, *, bm: int = 128, bn: int = 128,
     grid = (p, mp // bm_, np_ // bn_, cp // bc_)
     acc_dtype = (jnp.int32 if jnp.issubdtype(table.dtype, jnp.integer)
                  else jnp.float32)
+    tile_bytes = bc_ * 256 * bn_ * table.dtype.itemsize
 
     y = pl.pallas_call(
         functools.partial(_lut_kernel, nc=grid[3], bc=bc_),
@@ -217,13 +236,15 @@ def lut_gather_matmul(idx, table, *, bm: int = 128, bn: int = 128,
                                lambda pp, i, j, cc: (pp, i, j)),
         out_shape=jax.ShapeDtypeStruct((p, mp, np_), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm_, bn_), acc_dtype)],
-        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit(2 * tile_bytes)),
+        interpret=resolve_interpret(interpret),
     )(idx, table)
     return y[:, :m, :n]
 
 
 def _spike_matmul_grouped(x_packed, w, *, bm: int, bn: int, bk: int,
-                          interpret: bool):
+                          interpret: bool | None):
     """(G, M, K) uint8 plane groups x (K, N) -> (G, 8, M, N) per-plane dots.
 
     Grid (G, M/bm, N/bn, K/bk): for each group the inner three dims replay the
@@ -253,6 +274,6 @@ def _spike_matmul_grouped(x_packed, w, *, bm: int, bn: int, bk: int,
                                lambda gg, i, j, kk: (gg, 0, i, j)),
         out_shape=jax.ShapeDtypeStruct((g, 8, mp, np_), jnp.float32),
         scratch_shapes=[pltpu.VMEM((8, bm_, bn_), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x_packed, w)
     return y[:, :, :m, :n]

@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .device import resolve_interpret
+
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, *, nkv: int, scale: float):
     """q_ref: (1, bq, dh); k_ref/v_ref: (1, bkv, dh); o_ref: (1, bq, dh)."""
@@ -43,7 +45,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, *, nkv: int, scale: float):
 
 @functools.partial(jax.jit, static_argnames=("scale", "bq", "bkv", "interpret"))
 def stdp_attention(q, k, v, *, scale: float, bq: int = 128, bkv: int = 128,
-                   interpret: bool = True):
+                   interpret: bool | None = None):
     """q, k, v: (BH, N, Dh) spike-valued ({0,1}) or real tensors."""
     bh, n, dh = q.shape
     bq_, bkv_ = min(bq, n), min(bkv, n)
@@ -66,6 +68,6 @@ def stdp_attention(q, k, v, *, scale: float, bq: int = 128, bkv: int = 128,
         out_specs=pl.BlockSpec((1, bq_, dh), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, npad, dh), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bq_, dh), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)
     return y[:, :n, :]

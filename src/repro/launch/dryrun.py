@@ -29,7 +29,6 @@ from ..configs.base import (SHAPES, ARCH_IDS, get_config, cell_applicable,
 from . import steps
 from .hlo_analysis import analyze
 from .mesh import make_production_mesh
-from ..sharding.compat import set_mesh
 
 # --- TPU v5e hardware model -------------------------------------------------
 PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
@@ -49,7 +48,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
     mesh = make_production_mesh(multi_pod=multi_pod)
     spec = input_specs(cfg, shape)
     t0 = time.time()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             # >=100B configs: bf16 grad accumulation + smaller microbatch,
             # or params+moments+grads+activations exceed 16 GB HBM per chip

@@ -30,7 +30,6 @@ from ..configs.base import get_config
 from ..nn import transformer as T
 from . import steps
 from .mesh import make_cpu_mesh
-from ..sharding.compat import set_mesh
 
 
 @dataclasses.dataclass
@@ -164,7 +163,7 @@ def main(argv=None):
         cfg = cfg.reduced()
 
     mesh = make_cpu_mesh()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         eng = Engine(cfg, slots=args.slots, cache_len=args.cache_len,
                      seed=args.seed)
         rng = jax.random.PRNGKey(args.seed + 1)

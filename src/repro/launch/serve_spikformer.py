@@ -35,6 +35,7 @@ from ..infer.engine import Request
 from ..obs import Tracer, write_chrome_trace, write_spans_jsonl
 from ..serve import (AsyncServeRuntime, ServeFleet, ServePolicy,
                      image_maker, poisson_trace, run_open_loop)
+from .compile_cache import enable_compile_cache
 
 # Pre-split names, kept importable: ImageRequest is the engine Request;
 # SpikformerEngine is a construct-from-params convenience over the split.
@@ -133,6 +134,7 @@ def main(argv=None):
     ap.add_argument("--smoke", action="store_true",
                     help="CI smoke: few requests, assert completion/shapes")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.smoke:
         args.requests = min(args.requests, 5)

@@ -37,7 +37,6 @@ from ..nn import transformer as T
 from ..sharding import rules
 from . import steps
 from .mesh import make_cpu_mesh
-from ..sharding.compat import set_mesh
 
 
 def build_parser():
@@ -110,7 +109,7 @@ def main(argv=None):
         batch_shapes["frames"] = jax.ShapeDtypeStruct(
             (args.global_batch, cfg.n_frames, cfg.d_model), jnp.bfloat16)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         step_fn, (p_sh, o_sh, _), in_sh = steps.jit_train_step(
             cfg, mesh, ts, batch_shapes)
 

@@ -15,11 +15,11 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import get_abstract_mesh
 
 from .module import KeyStream
 from .layers import linear_init, linear, apply_rope, apply_mrope, rmsnorm_init, rmsnorm
 from ..sharding.hints import shard_hint
-from ..sharding.compat import get_abstract_mesh
 
 NEG_INF = -1e30
 

@@ -1,6 +1,6 @@
 """Sharding: path-based parameter rules + activation hints.
 
-The public surface, in three layers:
+The public surface, in two layers:
 
 * ``rules`` — path -> PartitionSpec tables for parameters, optimizer
   state, batches and caches (FSDP x TP storage layout), plus the serving
@@ -10,12 +10,12 @@ The public surface, in three layers:
   ``None`` entries).
 * ``hints`` — ``shard_hint`` activation layout pins that no-op without an
   active mesh, so model code runs unchanged on one CPU device.
-* ``compat`` — the jax-version shims (``set_mesh``,
-  ``get_abstract_mesh``, ``abstract_mesh``) everything mesh-touching goes
-  through.
+
+Mesh contexts are plain ``jax.set_mesh`` / ``jax.sharding.get_abstract_mesh``;
+meshes come from ``repro.launch.mesh`` (``Auto`` axes, so hints steer the
+partitioner instead of asserting).
 """
-from . import compat, hints, rules
-from .compat import abstract_mesh, get_abstract_mesh, set_mesh
+from . import hints, rules
 from .hints import shard_hint
 from .rules import (batch_shardings, cache_shardings, dp_axes,
                     opt_state_shardings, param_shardings, replica_devices,
@@ -23,12 +23,10 @@ from .rules import (batch_shardings, cache_shardings, dp_axes,
 
 __all__ = [
     # submodules
-    "rules", "hints", "compat",
+    "rules", "hints",
     # rule tables + fleet placement
     "dp_axes", "spec_for", "param_shardings", "opt_state_shardings",
     "batch_shardings", "cache_shardings", "serving_mesh", "replica_devices",
     # activation hints
     "shard_hint",
-    # version shims
-    "set_mesh", "get_abstract_mesh", "abstract_mesh",
 ]

@@ -14,9 +14,7 @@ from __future__ import annotations
 import math
 
 import jax
-from jax.sharding import PartitionSpec as P
-
-from .compat import get_abstract_mesh
+from jax.sharding import PartitionSpec as P, get_abstract_mesh
 
 
 def shard_hint(x, *dims):

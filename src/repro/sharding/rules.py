@@ -16,7 +16,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..nn.module import map_with_path
-from .compat import abstract_mesh  # noqa: F401  (re-export for rule tests)
 
 
 def dp_axes(mesh: Mesh):

@@ -11,6 +11,7 @@ import pytest
 from repro.data.pipeline import (DataConfig, DataPipeline, synthetic_lm_batch,
                                  image_batch, TokenFileSource)
 from repro.checkpoint.checkpointer import Checkpointer
+from repro.launch.mesh import make_mesh
 
 
 # ---------------------------------------------------------------------------
@@ -154,7 +155,7 @@ def test_elastic_restore_onto_mesh(tmp_path):
     ck = Checkpointer(str(tmp_path))
     tree = _tree()
     ck.save(2, tree, block=True)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     sh = jax.tree_util.tree_map(
         lambda _: NamedSharding(mesh, P()), jax.eval_shape(lambda: tree))
     got, _ = ck.restore(skeleton=jax.eval_shape(lambda: tree), shardings=sh)

@@ -10,6 +10,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.launch.mesh import make_mesh
+
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
@@ -73,12 +75,11 @@ def test_moe_arch_trains(tmp_path):
 def test_engine_matches_sequential_generation():
     from repro.configs.base import get_config
     from repro.launch.serve import Engine, Request
-    from repro.sharding.compat import set_mesh
     from repro.nn import transformer as T
 
     cfg = get_config("smollm-360m").reduced()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
-    with set_mesh(mesh):
+    mesh = make_mesh((1, 1), ("data", "model"))
+    with jax.set_mesh(mesh):
         # fp32 end-to-end: greedy argmax on an UNTRAINED model is otherwise
         # numerically unstable (logit gaps < bf16 eps flip between batchings)
         eng = Engine(cfg, slots=2, cache_len=64, seed=0,
@@ -134,11 +135,11 @@ def test_hlo_flops_scan_known():
 
 
 def test_hlo_collective_bytes_psum():
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.launch.hlo_analysis import analyze
 
-    mesh = jax.make_mesh((1,), ("x",))
+    mesh = make_mesh((1,), ("x",))
     n = 4096
 
     def f(x):

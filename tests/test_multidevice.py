@@ -21,7 +21,7 @@ SCRIPT = textwrap.dedent("""
     from repro.nn import transformer as T
     from repro.launch import steps
     from repro.optim import adamw
-    from repro.sharding.compat import set_mesh
+    from repro.launch.mesh import make_mesh
 
     cfg = get_config("smollm-360m").reduced(
         n_layers=2, d_model=128, n_heads=4, n_kv_heads=2, vocab=512)
@@ -38,9 +38,9 @@ SCRIPT = textwrap.dedent("""
     p_ref, o_ref, m_ref = plain(params, opt, batch)
 
     # sharded on 2x4
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     bs = {k: jax.ShapeDtypeStruct(v.shape, v.dtype) for k, v in batch.items()}
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         sharded, _, in_sh = steps.jit_train_step(cfg, mesh, ts, bs)
         # shard + donate COPIES (x.copy() — device_put alone may alias the
         # origin buffer for replicated leaves, and donation deletes it)
@@ -64,7 +64,7 @@ SCRIPT = textwrap.dedent("""
     dec_batch = {"tokens": toks, "cache_pos": jnp.int32(0)}
     ref_logits, _, _ = T.model_apply(params, dec_batch, cfg, mode="decode",
                                      cache=cache, compute_dtype=jnp.float32)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         cache_sh = jax.eval_shape(lambda: T.init_cache(cfg, B, S,
                                                        dtype=jnp.float32))
         fn, _, in_sh2 = steps.jit_serve_step(

@@ -4,6 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.launch.mesh import make_mesh
 from repro.optim import adamw
 from repro.optim.compression import ef_init, ef_compress, compressed_psum_int8
 
@@ -89,8 +90,8 @@ def test_topk_keeps_largest():
 def test_compressed_psum_matches_mean():
     """shard_map int8 all-reduce == fp32 mean within quantization error."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
-    mesh = jax.make_mesh((1,), ("x",))
+    from jax import shard_map
+    mesh = make_mesh((1,), ("x",))
     x = jnp.array([[1.0, -2.0, 3.0, 0.5]])
 
     f = shard_map(lambda v: compressed_psum_int8(v[0], "x")[None],

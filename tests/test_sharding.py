@@ -6,22 +6,21 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AbstractMesh, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import get_config
 from repro.nn import transformer as T
 from repro.sharding import rules
-from repro.sharding.compat import set_mesh
 from repro.sharding.hints import shard_hint
 from repro.launch import steps
+from repro.launch.mesh import make_mesh
 
 
 def fake_mesh(data=4, model=2, pod=None):
     """An abstract mesh over fake devices (no allocation) for rule tests."""
     if pod:
-        return rules.abstract_mesh((pod, data, model),
-                                   ("pod", "data", "model"))
-    return rules.abstract_mesh((data, model), ("data", "model"))
+        return AbstractMesh((pod, data, model), ("pod", "data", "model"))
+    return AbstractMesh((data, model), ("data", "model"))
 
 
 # AbstractMesh lacks .devices; spec_for only uses .shape/.axis_names, so this
@@ -83,7 +82,7 @@ def test_sharded_train_step_matches_unsharded():
     """jit with explicit shardings on a 1-device mesh == plain execution
     (numerical path identity for the full train step)."""
     cfg = get_config("smollm-360m").reduced()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     batch_shapes = {
         "tokens": jax.ShapeDtypeStruct((4, 32), jnp.int32),
         "labels": jax.ShapeDtypeStruct((4, 32), jnp.int32),
@@ -99,7 +98,7 @@ def test_sharded_train_step_matches_unsharded():
     plain = steps.make_train_step(cfg, ts)
     p2, o2, m2 = jax.jit(plain)(params, opt, batch)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         # donate_argnums consumes params/opt — run the plain step first
         step_sharded, _, _ = steps.jit_train_step(cfg, mesh, ts, batch_shapes)
         p1, o1, m1 = step_sharded(params, opt, batch)
@@ -108,7 +107,7 @@ def test_sharded_train_step_matches_unsharded():
 
 def test_batch_and_cache_shardings_build():
     cfg = get_config("hymba-1.5b")
-    mesh_real = jax.make_mesh((1, 1), ("data", "model"))
+    mesh_real = make_mesh((1, 1), ("data", "model"))
     cache_shapes = jax.eval_shape(lambda: T.init_cache(cfg, 4, 4096))
     c_sh = rules.cache_shardings(mesh_real, cache_shapes)
     for leaf in jax.tree_util.tree_leaves(
@@ -125,11 +124,10 @@ def test_public_import_surface():
     fleet (and training) consumes is importable from the package root and
     declared in __all__."""
     import repro.sharding as sharding
-    for name in ("rules", "hints", "compat", "dp_axes", "spec_for",
+    for name in ("rules", "hints", "dp_axes", "spec_for",
                  "param_shardings", "opt_state_shardings",
                  "batch_shardings", "cache_shardings", "serving_mesh",
-                 "replica_devices", "shard_hint", "set_mesh",
-                 "get_abstract_mesh", "abstract_mesh"):
+                 "replica_devices", "shard_hint"):
         assert name in sharding.__all__, name
         assert getattr(sharding, name) is not None
     # the package re-export is the module symbol, not a copy
