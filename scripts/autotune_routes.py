@@ -27,16 +27,8 @@ run under the Pallas interpreter — the samples are flagged and the fit
 describes the interpreter, so refit on a TPU host before committing the
 constants to a servable plan.
 
-``--profile`` runs the OTHER measurement this script owns: instead of
-timing isolated (M, K, N, G) grid points, it compiles a reduced
-Spikformer and times every layer of one real forward in place
-(``CompiledModel.profile_step`` — sync-barriered, eager ops), printing
-the per-layer table and a per-route aggregate. The grid fit answers
-"what should the cost constants be"; the profile answers "where does a
-real step's time actually go under the routes those constants chose".
-
   PYTHONPATH=src python scripts/autotune_routes.py [--fast] [--pallas] \
-      [--profile] [--out routes.json]
+      [--out routes.json]
 """
 from __future__ import annotations
 
@@ -340,47 +332,10 @@ def fit_pallas_constants(samples: list, pallas_samples: list, *,
         pallas_dot_cost=clip(dc, base.pallas_dot_cost))
 
 
-def profile_model(*, batch: int = 2, seed: int = 0) -> list:
-    """Compile the reduced Spikformer and print ``profile_step``'s
-    per-layer measured table plus a per-route aggregate. Returns the rows.
-
-    The reduced config is the same one the test suite and bench harness
-    compile, so the layer shapes (hence the route decisions being timed)
-    are the repo's real ones, just at calibration scale."""
-    from repro.core.spikformer import SpikformerConfig, init
-    from repro.infer.compile import ExecutionPlan, compile as infer_compile
-
-    cfg = SpikformerConfig().scaled()
-    params = init(jax.random.PRNGKey(seed), cfg)
-    model = infer_compile(params, cfg, ExecutionPlan(
-        batch_buckets=(batch,), weight_dtype="int8"))
-    rows = model.profile_step()
-    per_route = {}
-    for r in rows:
-        print(json.dumps({**r, "seconds": round(r["seconds"], 6)}))
-        agg = per_route.setdefault(r["route"], [0, 0.0])
-        agg[0] += 1
-        agg[1] += r["seconds"]
-    total = sum(r["seconds"] for r in rows) or 1.0
-    print(json.dumps({
-        "profile_batch": batch,
-        "layers": len(rows),
-        "total_s": round(total, 6),
-        "per_route": {route: {"layers": n, "total_s": round(t, 6),
-                              "share": round(t / total, 4)}
-                      for route, (n, t) in sorted(per_route.items())},
-    }, indent=1, sort_keys=True))
-    return rows
-
-
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--fast", action="store_true",
                     help="half the grid, one repeat (CI/smoke)")
-    ap.add_argument("--profile", action="store_true",
-                    help="compile the reduced model and print the per-layer "
-                         "measured table (CompiledModel.profile_step) "
-                         "instead of fitting route constants")
     ap.add_argument("--repeats", type=int, default=None)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--pallas", action="store_true",
@@ -395,9 +350,6 @@ def main(argv=None):
                     help="write the ExecutionPlan JSON fragment here "
                          "(stdout always gets it)")
     args = ap.parse_args(argv)
-
-    if args.profile:
-        return profile_model(seed=args.seed)
 
     grid = FAST_GRID if args.fast else GRID
     repeats = args.repeats or (1 if args.fast else 3)

@@ -4,17 +4,20 @@ Reads the versioned span JSONL ``repro.obs.export.write_spans_jsonl``
 emits and prints the three views a latency investigation starts with:
 
   * per-phase breakdown — count / total / mean wall time per span name
-    (admit, queue, place, assemble, step, complete, window ops, layers),
+    (admit, queue, idle, hold, place, assemble, occupancy, step, finish,
+    complete, window ops),
   * the top-N slowest requests (the ``complete`` span IS the request's
-    latency, so sorting them is the tail),
+    latency, so sorting them is the tail), each with the batch that
+    finished it and that batch's hold, assemble, occupancy and step times,
   * per-replica utilization — each replica's ``step`` time over the trace
     wall, the "is one replica dragging" readout for a fleet trace.
 
 ``--assert-complete`` turns the report into a gate (the CI trace-smoke
 step): every admitted request must carry its full rid-scoped span chain
 (``admit -> queue -> complete``; empty-payload admits legitimately skip
-``queue`` — they never enter the queue) and the ring must not have
-dropped spans. Exit 1 with the missing rids on violation.
+``queue`` — they never enter the queue), every ``queue`` and ``complete``
+span must name a batch whose ``step`` is in the trace, and the ring must
+not have dropped spans. Exit 1 with the violations.
 
   PYTHONPATH=src python scripts/trace_report.py trace.jsonl \
       [--top 5] [--assert-complete]
@@ -50,6 +53,22 @@ def slowest_requests(spans, n: int = 5) -> list:
     return sorted(done, key=lambda s: s.duration_s, reverse=True)[:n]
 
 
+def batch_key(span):
+    """A batch's identity: ids count up per client, or per replica in a
+    fleet."""
+    return span.replica, span.batch
+
+
+def batch_phases(spans) -> dict:
+    """{(replica, batch): {phase: seconds}} for the batch-scoped spans
+    (hold, place, assemble, occupancy, step, finish)."""
+    out = collections.defaultdict(lambda: collections.defaultdict(float))
+    for s in spans:
+        if s.category == "batch" and s.batch is not None:
+            out[batch_key(s)][s.name] += s.duration_s
+    return out
+
+
 def replica_utilization(spans) -> dict:
     """{replica: step_time / trace_wall} — how much of the trace each
     replica spent inside ``model.step``. Replica None is the single-worker
@@ -66,8 +85,10 @@ def replica_utilization(spans) -> dict:
 
 
 def check_complete(spans, dropped_spans: int) -> list:
-    """Every admitted request's rid-scoped chain must close. Returns the
-    violations (empty list = the trace passes)."""
+    """Every admitted request's rid-scoped chain must close, and its
+    ``queue`` and ``complete`` spans must name a batch whose ``step`` is
+    in the trace. Returns the violations (empty list = the trace
+    passes)."""
     by_rid = collections.defaultdict(set)
     admit_value = {}
     for s in spans:
@@ -77,6 +98,19 @@ def check_complete(spans, dropped_spans: int) -> list:
         if s.name == "admit":
             admit_value[s.rid] = s.value
     problems = []
+    stepped = {batch_key(s) for s in spans
+               if s.category == "batch" and s.name == "step"}
+    for s in spans:
+        if s.category != "request" or s.name not in ("queue", "complete") \
+                or batch_key(s) in stepped:
+            continue
+        # a zero-image admit completes at the door, in no batch
+        if s.name == "complete" and s.batch is None \
+                and not admit_value.get(s.rid):
+            continue
+        problems.append(f"rid {s.rid}: {s.name} span names batch "
+                        f"{s.batch} (replica {s.replica}), which has no "
+                        "step span")
     if dropped_spans:
         problems.append(f"ring dropped {dropped_spans} spans — the trace "
                         "is lossy; raise the tracer capacity")
@@ -115,11 +149,15 @@ def main(argv=None):
 
     slow = slowest_requests(spans, args.top)
     if slow:
+        phases = batch_phases(spans)
         print(f"\ntop {len(slow)} slowest requests:")
         for s in slow:
             rep = "" if s.replica is None else f" replica={s.replica}"
+            ph = phases.get(batch_key(s), {})
+            times = " ".join(f"{k}={ph.get(k, 0.0) * 1e3:.3f}ms" for k in
+                             ("hold", "assemble", "occupancy", "step"))
             print(f"  rid={s.rid:<6} latency={s.duration_s * 1e3:8.3f}ms"
-                  f"{rep}")
+                  f"{rep} batch={s.batch} {times}")
 
     util = replica_utilization(spans)
     if util:

@@ -204,6 +204,22 @@ def fold_inference_params(params, cfg: SpikformerConfig):
     return out
 
 
+def layer_paths(cfg: SpikformerConfig) -> list:
+    """Every layer of one ``forward_folded`` pass, in call order: the stem's
+    convs, then each block's q/k/v projections, its STDP attention, the
+    output projection and the two MLP layers. The one list of layer paths:
+    ``forward_folded`` selects each layer's weights and names its
+    ``jax.named_scope`` by it, and ``infer.compile.linear_layer_paths``
+    (route planning, occupancy calibration) is this list without the
+    attention."""
+    paths = [f"scs/conv{i}" for i in range(len(cfg.scs_channels))]
+    for i in range(cfg.depth):
+        paths += [f"blocks/b{i}/ssa/{op}"
+                  for op in ("wq", "wk", "wv", "stdp", "wo")]
+        paths += [f"blocks/b{i}/mlp/fc1", f"blocks/b{i}/mlp/fc2"]
+    return paths
+
+
 def forward_folded(folded, images_u8, cfg: SpikformerConfig, *, backend,
                    layer_occupancy=None):
     """The inference forward over BN-folded params through a pluggable
@@ -229,40 +245,48 @@ def forward_folded(folded, images_u8, cfg: SpikformerConfig, *, backend,
     is forwarded to a backend method only for layers that carry a value,
     so backends without the ``occupancy`` parameter keep working under
     dense plans. Returns (B, num_classes) logits.
+
+    Each layer runs under a ``jax.named_scope`` of its path (the fused MLP
+    pair under ``blocks/b{i}/mlp``), the token reshape under ``tokens`` and
+    the readout under ``head``, so the compiled program's op metadata says
+    which layer each device op belongs to.
     """
     t = cfg.timesteps
     occ = layer_occupancy or {}
+    paths = iter(layer_paths(cfg))      # call order: each call takes its own
 
     def extra(path):
         o = occ.get(path)
         return {} if o is None else {"occupancy": o}
 
-    def wssl(z, layer, path):
-        return backend.wssl_lif(z, layer["kernel"], layer["bias"], t=t,
-                                scale=layer.get("scale"),
-                                lut=layer.get("lut"), **extra(path))
+    def at(path):
+        node = folded
+        for key in path.split("/"):
+            node = node[key]
+        return node
 
-    c0 = folded["scs"]["conv0"]
-    x = backend.sssc_lif(images_u8, c0["kernel"], c0["bias"], t=t,
-                         scale=c0.get("scale"), lut=c0.get("lut"),
-                         **extra("scs/conv0"))
-    for i in range(1, len(cfg.scs_channels)):
-        ci = folded["scs"][f"conv{i}"]
-        x = backend.zsc_lif(x, ci["kernel"], ci["bias"], t=t,
-                            scale=ci.get("scale"), lut=ci.get("lut"),
-                            **extra(f"scs/conv{i}"))
-    x = backend.to_tokens(x)
+    def linear(op, z, path):
+        layer = at(path)
+        with jax.named_scope(path):
+            return op(z, layer["kernel"], layer["bias"], t=t,
+                      scale=layer.get("scale"), lut=layer.get("lut"),
+                      **extra(path))
 
-    for i in range(cfg.depth):
-        blk = folded["blocks"][f"b{i}"]
-        ssa, mlp = blk["ssa"], blk["mlp"]
-        bp = f"blocks/b{i}"
-        q = wssl(x, ssa["wq"], f"{bp}/ssa/wq")
-        k = wssl(x, ssa["wk"], f"{bp}/ssa/wk")
-        v = wssl(x, ssa["wv"], f"{bp}/ssa/wv")
-        att = backend.stdp_lif(q, k, v, heads=cfg.heads,
-                               scale=cfg.attn_scale, t=t)
-        att = wssl(att, ssa["wo"], f"{bp}/ssa/wo")
+    x = linear(backend.sssc_lif, images_u8, next(paths))
+    for _ in cfg.scs_channels[1:]:
+        x = linear(backend.zsc_lif, x, next(paths))
+    with jax.named_scope("tokens"):
+        x = backend.to_tokens(x)
+
+    for _ in range(cfg.depth):
+        wq, wk, wv, stdp, wo, fc1, fc2 = (next(paths) for _ in range(7))
+        q = linear(backend.wssl_lif, x, wq)
+        k = linear(backend.wssl_lif, x, wk)
+        v = linear(backend.wssl_lif, x, wv)
+        with jax.named_scope(stdp):
+            att = backend.stdp_lif(q, k, v, heads=cfg.heads,
+                                   scale=cfg.attn_scale, t=t)
+        att = linear(backend.wssl_lif, att, wo)
         x = backend.residual(att, x, cfg.residual)
         # backends exposing ``mlp_pair_lif`` may fuse the fc1 -> LIF -> fc2
         # step into one kernel (packed spikes never unpacked in HBM); a
@@ -272,18 +296,19 @@ def forward_folded(folded, images_u8, cfg: SpikformerConfig, *, backend,
         s2 = None
         pair = getattr(backend, "mlp_pair_lif", None)
         if pair is not None:
-            s2 = pair(x, mlp["fc1"], mlp["fc2"], t=t,
-                      **extra(f"{bp}/mlp/fc1"))
+            with jax.named_scope(fc1.rsplit("/", 1)[0]):
+                s2 = pair(x, at(fc1), at(fc2), t=t, **extra(fc1))
         if s2 is None:
-            s1 = wssl(x, mlp["fc1"], f"{bp}/mlp/fc1")
-            s2 = wssl(s1, mlp["fc2"], f"{bp}/mlp/fc2")
+            s2 = linear(backend.wssl_lif, linear(backend.wssl_lif, x, fc1),
+                        fc2)
         x = backend.residual(s2, x, cfg.residual)
 
-    rate = backend.rate(x, t=t)                         # (B, D)
-    head = folded["head"]
-    logits = rate @ head["kernel"].astype(rate.dtype)
-    if "bias" in head:
-        logits = logits + head["bias"].astype(logits.dtype)
+    with jax.named_scope("head"):
+        rate = backend.rate(x, t=t)                     # (B, D)
+        head = folded["head"]
+        logits = rate @ head["kernel"].astype(rate.dtype)
+        if "bias" in head:
+            logits = logits + head["bias"].astype(logits.dtype)
     return logits
 
 

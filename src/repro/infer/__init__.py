@@ -11,7 +11,7 @@ from .backends import (FloatBackend, OccupancyRecorder, PackedBackend,
 from .compile import (CompiledModel, ExecutionPlan,
                       calibrate_layer_occupancy, compile, fold_bn,
                       linear_layer_paths, lower, plan_route_tables,
-                      profile_layer_paths, quantize_weights,
+                      quantize_weights,
                       replicate_model, strip_lut_annotations)
 from .engine import (PAPER_FPS, SERVE_STATS_VERSION, MicroBatchEngine,
                      QueueDepthWatermark, Request, ServeClient,
@@ -26,7 +26,6 @@ __all__ = [
     "fold_bn", "quantize_weights", "plan_route_tables", "lower",
     "strip_lut_annotations",
     "calibrate_layer_occupancy", "linear_layer_paths",
-    "profile_layer_paths",
     # serve half
     "MicroBatchEngine", "Request", "PAPER_FPS", "batch_occupancy",
     "ServeClient", "serve_stats", "SERVE_STATS_VERSION",

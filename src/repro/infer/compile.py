@@ -292,12 +292,10 @@ def linear_layer_paths(cfg: SpikformerConfig) -> list:
     """Layer paths in FORWARD-CALL order — the order a single
     ``forward_folded`` pass hits each spiking linear, which is the order
     ``backends.OccupancyRecorder`` appends its trace in. (``map_folded_layers``
-    walks the same paths but in tree order; calibration needs call order.)"""
-    paths = [f"scs/conv{i}" for i in range(len(cfg.scs_channels))]
-    for i in range(cfg.depth):
-        paths += [f"blocks/b{i}/ssa/{w}" for w in ("wq", "wk", "wv", "wo")]
-        paths += [f"blocks/b{i}/mlp/fc1", f"blocks/b{i}/mlp/fc2"]
-    return paths
+    walks the same paths but in tree order; calibration needs call order.)
+    ``spikformer.layer_paths`` without the STDP attention."""
+    return [p for p in spikformer.layer_paths(cfg)
+            if not p.endswith("/stdp")]
 
 
 def calibrate_layer_occupancy(params, cfg: SpikformerConfig, images_u8, *,
@@ -328,67 +326,6 @@ def calibrate_layer_occupancy(params, cfg: SpikformerConfig, images_u8, *,
             f"config has {len(paths)} spiking linears — recorder and "
             "forward_folded disagree about the layer sequence")
     return dict(zip(paths, recorder.trace))
-
-
-def profile_layer_paths(cfg: SpikformerConfig) -> list:
-    """Every timed op of one profiled forward pass, in call order: the
-    spiking linears (``linear_layer_paths``) interleaved with each block's
-    STDP attention (``blocks/b{i}/ssa/stdp``) exactly where
-    ``forward_folded`` calls it. The two-layer MLP path is assumed — a
-    profiling backend never exposes ``mlp_pair_lif``, so the op sequence
-    is deterministic regardless of the serving backend's fusion."""
-    paths = [f"scs/conv{i}" for i in range(len(cfg.scs_channels))]
-    for i in range(cfg.depth):
-        paths += [f"blocks/b{i}/ssa/{w}" for w in ("wq", "wk", "wv")]
-        paths += [f"blocks/b{i}/ssa/stdp"]
-        paths += [f"blocks/b{i}/ssa/wo"]
-        paths += [f"blocks/b{i}/mlp/fc1", f"blocks/b{i}/mlp/fc2"]
-    return paths
-
-
-class _LayerTimer:
-    """A backend wrapper that times every dataflow layer sync-barriered:
-    each op's output is ``block_until_ready`` before the clock stops, so
-    a layer's wall time is its own, not its successor's dispatch queue.
-    Appends ``(t0, t1)`` to ``trace`` in forward call order (the
-    ``OccupancyRecorder`` idiom). Deliberately does NOT expose
-    ``mlp_pair_lif``: the two-layer MLP composition runs, keeping the op
-    sequence aligned with ``profile_layer_paths``. Bookkeeping ops
-    (residual, to_tokens, rate) delegate untimed — they are reshapes and
-    popcounts, not the PE-array work VESTA's area budget is about."""
-
-    def __init__(self, inner, *, clock=time.perf_counter):
-        self._inner = inner
-        self._clock = clock
-        self.trace: list[tuple] = []
-
-    def _timed(self, fn, *args, **kw):
-        t0 = self._clock()
-        out = fn(*args, **kw)
-        jax.block_until_ready(out)
-        self.trace.append((t0, self._clock()))
-        return out
-
-    def sssc_lif(self, *args, **kw):
-        return self._timed(self._inner.sssc_lif, *args, **kw)
-
-    def zsc_lif(self, *args, **kw):
-        return self._timed(self._inner.zsc_lif, *args, **kw)
-
-    def wssl_lif(self, *args, **kw):
-        return self._timed(self._inner.wssl_lif, *args, **kw)
-
-    def stdp_lif(self, *args, **kw):
-        return self._timed(self._inner.stdp_lif, *args, **kw)
-
-    def residual(self, *args, **kw):
-        return self._inner.residual(*args, **kw)
-
-    def to_tokens(self, *args, **kw):
-        return self._inner.to_tokens(*args, **kw)
-
-    def rate(self, *args, **kw):
-        return self._inner.rate(*args, **kw)
 
 
 def lower(folded, cfg: SpikformerConfig, backend, *, jit: bool = True,
@@ -537,77 +474,6 @@ class CompiledModel:
     def classify(self, images_u8):
         """(N, H, W, C) uint8 -> (N,) int32 argmax class ids."""
         return jnp.argmax(self.logits(images_u8), axis=-1).astype(jnp.int32)
-
-    # -- profiling ----------------------------------------------------------
-
-    def profile_step(self, images_u8=None, *, tracer=None,
-                     clock=time.perf_counter) -> list:
-        """Per-layer wall times for ONE forward pass, sync-barriered.
-
-        Runs an un-jitted forward through a ``_LayerTimer`` wrapping this
-        model's backend (the ``calibrate_layer_occupancy`` recipe: eager
-        ops, trace zipped with the known call order) and returns one row
-        per timed op::
-
-            {"path": "blocks/b0/ssa/wq", "route": "lut_sparse",
-             "seconds": 1.3e-4, "occupancy": 0.31}
-
-        ``route`` is the resolved plan's decision for that layer ("stdp"
-        for the attention op — it has no matmul route); ``occupancy`` is
-        the plan's calibrated chunk occupancy, or None if uncalibrated.
-        Defaults to zeros at the largest bucket (the planning shape) when
-        no ``images_u8`` is given — layer timing is shape-bound, and real
-        pixels matter only when the sparse route's work depends on them,
-        in which case pass the calibration batch.
-
-        Eager per-op timing measures the op-level kernels a fused jit
-        step would optimize across, so the rows are RELATIVE weight — the
-        measured table ``scripts/autotune_routes.py --profile`` prints to
-        seed route-constant fits — not a goodput prediction; the jitted
-        ``step()`` stays the serving truth.
-
-        With a ``tracer``, each row is also emitted as a ``("layer",
-        path)`` span tagged with the route (as ``bucket=None`` — routes
-        are strings, so the route rides in the row; spans carry the
-        occupancy and ``value=seconds``).
-        """
-        if images_u8 is None:
-            images_u8 = jnp.zeros(self.input_shape(), jnp.uint8)
-        images_u8 = jnp.asarray(images_u8, jnp.uint8)
-        if images_u8.shape[0] not in self.buckets:
-            raise ValueError(
-                f"profile batch of {images_u8.shape[0]} is not a compiled "
-                f"bucket {self.buckets}; profiling times the shapes serving "
-                "will run")
-        timer = _LayerTimer(self.backend, clock=clock)
-        occ_all = self.plan.layer_occupancy or {}
-        sparse_occ = {p: occ_all[p]
-                      for p, r in (self.plan.routes or {}).items()
-                      if r == "lut_sparse"} or None
-        fwd = lower(self.folded, self.cfg, timer, jit=False,
-                    layer_occupancy=sparse_occ)
-        jax.block_until_ready(fwd(self.folded, images_u8))
-        paths = profile_layer_paths(self.cfg)
-        if len(timer.trace) != len(paths):
-            raise RuntimeError(
-                f"layer-timing trace has {len(timer.trace)} entries but the "
-                f"config has {len(paths)} timed ops — timer and "
-                "forward_folded disagree about the op sequence")
-        routes = self.plan.routes or {}
-        rows = []
-        for path, (t0, t1) in zip(paths, timer.trace):
-            occ = occ_all.get(path)
-            default = "stdp" if path.endswith("/stdp") else "unpack"
-            rows.append({
-                "path": path,
-                "route": routes.get(path, default),
-                "seconds": t1 - t0,
-                "occupancy": occ,
-            })
-            if tracer is not None and tracer.enabled:
-                tracer.span("layer", path, t0=t0, t1=t1,
-                            occupancy=occ, value=t1 - t0)
-        return rows
 
     def __call__(self, images_u8):
         return self.logits(images_u8)

@@ -33,7 +33,8 @@ bit-identical labels through both.
 
 Observability (``repro.obs``): every ServeClient accepts a ``tracer`` and
 emits the canonical request lifecycle ``admit -> queue -> place ->
-assemble -> step -> complete`` as spans; completed-request latencies feed
+assemble -> step -> complete`` as spans, each batch-scoped span and each
+request's ``queue``/``complete`` span naming its batch's id; completed-request latencies feed
 a bounded ``LatencyHistogram`` so ``stats()`` percentiles cost O(buckets)
 memory however long the server lives.
 """
@@ -357,6 +358,7 @@ class MicroBatchEngine:
         self.done: list[Request] = []
         self._pending: dict[int, int] = {}  # rid -> images left
         self._next_rid = 0
+        self._next_batch = 0                # the next batch's id (spans)
         self._queue_depth = QueueDepthWatermark()
         self.latency_hist = LatencyHistogram()
         self.acct = StepAccounting()
@@ -465,11 +467,14 @@ class MicroBatchEngine:
         if not self.queue:
             return 0
         tr = self.tracer
+        bid = self._next_batch
+        self._next_batch += 1
         t_start = self._clock()
         bucket = self.pick_bucket(len(self.queue))
         t_place = self._clock()
         if tr.enabled:
-            tr.span("batch", "place", t0=t_start, t1=t_place, bucket=bucket)
+            tr.span("batch", "place", t0=t_start, t1=t_place, bucket=bucket,
+                    batch=bid)
         work = [self.queue.popleft()
                 for _ in range(min(bucket, len(self.queue)))]
         t_pop = self._clock()
@@ -478,18 +483,18 @@ class MicroBatchEngine:
                 if not req.t_dequeue:     # first image leaving the queue
                     req.t_dequeue = t_pop
                     tr.span("request", "queue", t0=req.t_submit, t1=t_pop,
-                            rid=req.rid)
+                            rid=req.rid, batch=bid)
         batch, _ = assemble_batch([req.images[i] for req, i in work], bucket)
         occ = batch_occupancy(batch[:len(work)])  # real rows only
         t0 = self._clock()
         if tr.enabled:
             tr.span("batch", "assemble", t0=t_pop, t1=t0, bucket=bucket,
-                    occupancy=occ, value=len(work))
+                    occupancy=occ, value=len(work), batch=bid)
         logits = np.asarray(self.model.step(batch))
         busy_s = self._clock() - t0
         if tr.enabled:
             tr.span("batch", "step", t0=t0, t1=t0 + busy_s, bucket=bucket,
-                    occupancy=occ, value=len(work))
+                    occupancy=occ, value=len(work), batch=bid)
             tr.counter("occupancy", occ, t=t0)
         labels = logits[:len(work)].argmax(axis=-1)
         now = self._clock()
@@ -503,7 +508,7 @@ class MicroBatchEngine:
                 self.latency_hist.observe(now - req.t_submit)
                 if tr.enabled:
                     tr.span("request", "complete", t0=req.t_submit, t1=now,
-                            rid=req.rid)
+                            rid=req.rid, batch=bid)
         self.acct.record_step(rows=len(work), bucket=bucket, busy_s=busy_s,
                               wall_s=self._clock() - t_start,
                               occupancy=occ)

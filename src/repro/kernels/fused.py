@@ -152,6 +152,7 @@ def tflif_lut_matmul(x, bias, table, *, v_th=V_TH, tau: float = TAU,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit(2 * (table.size * table.dtype.itemsize
                                              + x_block_bytes))),
+        name="tflif_lut_matmul",  # the kernel family in a device trace
         interpret=resolve_interpret(interpret),
     )(x.astype(jnp.float32), bias[None], v_th[None], table)
     return spikes[:, :r, :k], acc[:, :r, :]

@@ -122,6 +122,7 @@ def spike_matmul(x_packed, w, *, mode: str = "per_plane",
         out_specs=out_spec,
         out_shape=out_shape,
         scratch_shapes=[acc],
+        name="spike_matmul",  # the kernel family in a device trace
         interpret=resolve_interpret(interpret),
     )(x_packed, w)
 
@@ -238,6 +239,7 @@ def lut_gather_matmul(idx, table, *, bm: int = 128, bn: int = 128,
         scratch_shapes=[pltpu.VMEM((bm_, bn_), acc_dtype)],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit(2 * tile_bytes)),
+        name="lut_gather_matmul",  # the kernel family in a device trace
         interpret=resolve_interpret(interpret),
     )(idx, table)
     return y[:, :m, :n]
@@ -274,6 +276,7 @@ def _spike_matmul_grouped(x_packed, w, *, bm: int, bn: int, bk: int,
                                lambda gg, i, j, kk: (gg, 0, i, j)),
         out_shape=jax.ShapeDtypeStruct((g, 8, mp, np_), jnp.float32),
         scratch_shapes=[pltpu.VMEM((8, bm_, bn_), jnp.float32)],
+        name="spike_matmul",  # the kernel family in a device trace
         interpret=resolve_interpret(interpret),
     )(x_packed, w)
     return y[:, :, :m, :n]

@@ -68,6 +68,7 @@ def stdp_attention(q, k, v, *, scale: float, bq: int = 128, bkv: int = 128,
         out_specs=pl.BlockSpec((1, bq_, dh), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, npad, dh), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bq_, dh), jnp.float32)],
+        name="stdp_attention",  # the kernel family in a device trace
         interpret=resolve_interpret(interpret),
     )(q, k, v)
     return y[:, :n, :]

@@ -97,6 +97,7 @@ def tflif_fused(x, bias=None, *, tau: float = TAU, v_th=V_TH,
         ],
         out_specs=pl.BlockSpec((groups, br, LANES), lambda i: (0, i, 0)),
         out_shape=jax.ShapeDtypeStruct((groups, r, LANES), jnp.uint8),
+        name="tflif_fused",  # the kernel family in a device trace
         interpret=resolve_interpret(interpret),
     )(x.astype(jnp.float32).reshape(t_steps, r, LANES),
       bias.astype(jnp.float32).reshape(r, LANES), v_th.reshape(r, LANES))

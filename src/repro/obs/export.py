@@ -18,6 +18,10 @@ Two formats, two audiences:
   it back to ``Span`` records, so a trace file is a first-class input,
   not a write-only artifact.
 
+Both carry the tracer's clock ``anchor`` (a ``perf_counter`` reading and
+the ``time.time_ns()`` read beside it), so spans can be put on real time,
+and from there on a profiler trace's clock.
+
 .. _Trace Event Format:
    https://docs.google.com/document/d/1CvAClvFfyA5R-PhYUmn5OOQtYMH4h6I0nSsKchNAySU
 """
@@ -27,7 +31,10 @@ import json
 
 from .trace import Span
 
-SPANS_SCHEMA_VERSION = 1
+# v2: every span carries ``batch`` (the id of its batch; a request's queue
+#     and complete spans name the batch that took its first image and the
+#     one that finished it); the header carries the clock anchor.
+SPANS_SCHEMA_VERSION = 2
 SPANS_KIND = "repro.obs.spans"
 
 # fixed tid lanes inside each replica's pid; request lanes start above them
@@ -50,12 +57,20 @@ def _tid_for(span: Span) -> int:
     return _TID_WORKER
 
 
-def to_chrome_trace(spans, *, dropped_spans: int = 0) -> dict:
+def _anchor_dict(anchor) -> dict | None:
+    if anchor is None:
+        return None
+    perf_s, time_ns = anchor
+    return {"perf_counter_s": float(perf_s), "time_ns": int(time_ns)}
+
+
+def to_chrome_trace(spans, *, dropped_spans: int = 0, anchor=None) -> dict:
     """Render spans as a Chrome trace-event dict (Perfetto-loadable).
 
     Timestamps are rebased to the earliest span (the injected serving
     clock has an arbitrary origin) and scaled to microseconds, the
-    format's unit."""
+    format's unit. ``otherData`` keeps the rebase origin (``t_base_s``)
+    and the tracer's clock ``anchor``."""
     spans = list(spans)
     t_base = min((s.t0 for s in spans), default=0.0)
     events = []
@@ -70,7 +85,8 @@ def to_chrome_trace(spans, *, dropped_spans: int = 0) -> dict:
             continue
         tid = _tid_for(s)
         seen_pids.setdefault(pid, set()).add(tid)
-        args = {k: v for k, v in (("rid", s.rid), ("bucket", s.bucket),
+        args = {k: v for k, v in (("rid", s.rid), ("batch", s.batch),
+                                  ("bucket", s.bucket),
                                   ("occupancy", s.occupancy),
                                   ("value", s.value)) if v is not None}
         events.append({"ph": "X", "cat": s.category, "name": s.name,
@@ -84,12 +100,12 @@ def to_chrome_trace(spans, *, dropped_spans: int = 0) -> dict:
             name = _LANE_NAMES.get(tid, f"request {tid - _TID_REQUEST_BASE}")
             events.append({"ph": "M", "name": "thread_name", "pid": pid,
                            "tid": tid, "args": {"name": name}})
-    return {
-        "traceEvents": events,
-        "displayTimeUnit": "ms",
-        "otherData": {"spans_version": SPANS_SCHEMA_VERSION,
-                      "dropped_spans": int(dropped_spans)},
-    }
+    other = {"spans_version": SPANS_SCHEMA_VERSION,
+             "dropped_spans": int(dropped_spans)}
+    if anchor is not None:
+        other.update(t_base_s=t_base, clock_anchor=_anchor_dict(anchor))
+    return {"traceEvents": events, "displayTimeUnit": "ms",
+            "otherData": other}
 
 
 def write_chrome_trace(path, tracer, *, dropped_spans=None) -> int:
@@ -99,7 +115,8 @@ def write_chrome_trace(path, tracer, *, dropped_spans=None) -> int:
     spans = tracer.spans() if hasattr(tracer, "spans") else list(tracer)
     if dropped_spans is None:
         dropped_spans = getattr(tracer, "dropped_spans", 0)
-    doc = to_chrome_trace(spans, dropped_spans=dropped_spans)
+    doc = to_chrome_trace(spans, dropped_spans=dropped_spans,
+                          anchor=getattr(tracer, "anchor", None))
     with open(path, "w") as f:
         json.dump(doc, f)
     return len(spans)
@@ -108,13 +125,16 @@ def write_chrome_trace(path, tracer, *, dropped_spans=None) -> int:
 def write_spans_jsonl(path, tracer, *, meta: dict | None = None,
                       dropped_spans=None) -> int:
     """Write the versioned JSONL span file: one header line (schema
-    version, span count, ``dropped_spans``, caller ``meta``), then one
-    object per span. Returns the span count."""
+    version, span count, ``dropped_spans``, the tracer's ``clock_anchor``,
+    caller ``meta``), then one object per span. Returns the span count."""
     spans = tracer.spans() if hasattr(tracer, "spans") else list(tracer)
     if dropped_spans is None:
         dropped_spans = getattr(tracer, "dropped_spans", 0)
     header = {"kind": SPANS_KIND, "spans_version": SPANS_SCHEMA_VERSION,
               "spans": len(spans), "dropped_spans": int(dropped_spans)}
+    anchor = _anchor_dict(getattr(tracer, "anchor", None))
+    if anchor is not None:
+        header["clock_anchor"] = anchor
     if meta:
         header["meta"] = dict(meta)
     with open(path, "w") as f:
@@ -124,14 +144,17 @@ def write_spans_jsonl(path, tracer, *, meta: dict | None = None,
                 "cat": s.category, "name": s.name,
                 "t0": s.t0, "t1": s.t1, "rid": s.rid,
                 "replica": s.replica, "bucket": s.bucket,
-                "occ": s.occupancy, "value": s.value}) + "\n")
+                "occ": s.occupancy, "value": s.value,
+                "batch": s.batch}) + "\n")
     return len(spans)
 
 
 def load_spans_jsonl(path) -> tuple[dict, list[Span]]:
     """Load a span JSONL file back: ``(header, spans)``. Refuses files
-    that are not this format or a newer schema than this code reads —
-    a silent partial parse would corrupt every downstream report."""
+    that are not this format or another schema version than this code
+    reads — a silent partial parse would corrupt every downstream report
+    (a version-1 file has no batch ids, so no request can be linked to
+    the batch that served it)."""
     with open(path) as f:
         first = f.readline()
         if not first.strip():
@@ -145,7 +168,8 @@ def load_spans_jsonl(path) -> tuple[dict, list[Span]]:
         if version != SPANS_SCHEMA_VERSION:
             raise ValueError(
                 f"{path}: spans_version={version!r}; this reader speaks "
-                f"{SPANS_SCHEMA_VERSION}")
+                f"{SPANS_SCHEMA_VERSION} only (version 1 predates batch "
+                "ids: record the trace again)")
         spans = []
         for line in f:
             if not line.strip():
@@ -154,7 +178,7 @@ def load_spans_jsonl(path) -> tuple[dict, list[Span]]:
             spans.append(Span(d["cat"], d["name"], d["t0"], d["t1"],
                               d.get("rid"), d.get("replica"),
                               d.get("bucket"), d.get("occ"),
-                              d.get("value")))
+                              d.get("value"), d.get("batch")))
     if len(spans) != header.get("spans", len(spans)):
         raise ValueError(
             f"{path}: header promises {header.get('spans')} spans, file "

@@ -6,13 +6,20 @@ lifecycle as spans::
 
     admit -> queue -> place -> assemble -> step -> complete
 
-plus ``window`` spans from the event-stream session, ``layer`` spans from
-``CompiledModel.profile_step``, and ``counter`` samples (queue depth,
-occupancy). A span is nine scalar fields — category, name, start, end,
-request id, replica, bucket, occupancy, value — and the whole record set
-lives in a **preallocated column-oriented ring**: appending writes nine
-existing slots under a lock and allocates nothing, so tracing sits on the
-serving hot path without feeding the allocator. When the ring wraps, the
+The serving workers also cover their whole loop, so that consecutive spans
+share their boundary timestamps and tile the worker's timeline::
+
+    idle -> hold -> place -> assemble (occupancy inside) -> step -> finish
+
+plus ``window`` spans from the event-stream session and ``counter``
+samples (queue depth, occupancy). A span is ten scalar fields — category,
+name, start, end, request id, replica, bucket, occupancy, value, batch id
+— and the whole record set lives in a **preallocated column-oriented
+ring**: appending writes ten existing slots under a lock and allocates
+nothing, so tracing sits on the serving hot path without feeding the
+allocator. Every batch-scoped span carries its batch's id; a request's
+``queue`` span names the batch that took its first image and its
+``complete`` span the batch that finished it. When the ring wraps, the
 OLDEST span is overwritten and ``dropped_spans`` counts the loss loudly —
 a trace that silently forgot its beginning would lie about request
 chains, so every consumer (``obs.export``, ``scripts/trace_report.py``)
@@ -30,6 +37,11 @@ the pure scheduler): a test drives a fake clock and pins the exact span
 table, just like the PR 9 decision tables. Emit sites that already
 measured ``t0``/``t1`` on the serving clock pass them explicitly; a bare
 ``span()`` stamps an instant on the tracer's own clock.
+
+Each tracer records a clock ``anchor`` when it is made: a
+``(time.perf_counter(), time.time_ns())`` pair read back to back, which
+puts spans on the ``perf_counter`` clock onto real time (the exports carry
+it).
 """
 from __future__ import annotations
 
@@ -38,7 +50,7 @@ import time
 import typing
 
 SPAN_FIELDS = ("category", "name", "t0", "t1", "rid", "replica", "bucket",
-               "occupancy", "value")
+               "occupancy", "value", "batch")
 
 # The canonical request lifecycle, in order. ``place``/``assemble``/``step``
 # are batch-scoped (rid None — one span covers every request in the fused
@@ -50,7 +62,9 @@ LIFECYCLE = ("admit", "queue", "place", "assemble", "step", "complete")
 class Span(typing.NamedTuple):
     """One structured trace record. ``t0 == t1`` marks an instant event
     (counters, shed markers); ``value`` is the counter sample or a
-    span-specific scalar (rows for ``step``, depth for ``queue_depth``)."""
+    span-specific scalar (rows for ``step``, depth for ``queue_depth``);
+    ``batch`` is the id of the batch a span belongs to (counting up per
+    client, or per replica in a fleet)."""
     category: str
     name: str
     t0: float
@@ -60,6 +74,7 @@ class Span(typing.NamedTuple):
     bucket: int | None = None
     occupancy: float | None = None
     value: float | None = None
+    batch: int | None = None
 
     @property
     def duration_s(self) -> float:
@@ -76,6 +91,7 @@ class NullTracer:
     enabled = False
     dropped_spans = 0
     capacity = 0
+    anchor = None
 
     def span(self, category, name, **kw) -> None:
         pass
@@ -105,7 +121,7 @@ class Tracer:
         tr.spans()          # chronological list[Span]
         tr.dropped_spans    # how many oldest spans the ring overwrote
 
-    The ring is column-oriented: nine preallocated Python lists of
+    The ring is column-oriented: ten preallocated Python lists of
     ``capacity`` slots each. ``span()`` writes one slot per column at the
     write head and advances it — O(1), zero allocation, one lock. Span
     objects only materialize in ``spans()``, off the hot path.
@@ -118,6 +134,7 @@ class Tracer:
             raise ValueError(f"capacity must be >= 1, got {capacity!r}")
         self.capacity = int(capacity)
         self.clock = clock
+        self.anchor = (time.perf_counter(), time.time_ns())
         self.dropped_spans = 0
         self._lock = threading.Lock()
         self._head = 0          # next write slot
@@ -132,12 +149,13 @@ class Tracer:
         self._bucket = [None] * n
         self._occ = [None] * n
         self._value = [None] * n
+        self._batch = [None] * n
 
     def span(self, category: str, name: str, *, t0: float | None = None,
              t1: float | None = None, rid: int | None = None,
              replica: int | None = None, bucket: int | None = None,
              occupancy: float | None = None,
-             value: float | None = None) -> None:
+             value: float | None = None, batch: int | None = None) -> None:
         """Record one span. ``t0`` defaults to now (tracer clock); ``t1``
         defaults to ``t0`` (an instant event)."""
         if t0 is None:
@@ -155,6 +173,7 @@ class Tracer:
             self._bucket[i] = bucket
             self._occ[i] = occupancy
             self._value[i] = value
+            self._batch[i] = batch
             self._head = (i + 1) % self.capacity
             if self._count == self.capacity:
                 self.dropped_spans += 1     # overwrote the oldest span
@@ -181,7 +200,7 @@ class Tracer:
                 out.append(Span(self._cat[i], self._name[i], self._t0[i],
                                 self._t1[i], self._rid[i], self._replica[i],
                                 self._bucket[i], self._occ[i],
-                                self._value[i]))
+                                self._value[i], self._batch[i]))
         return out
 
     def clear(self) -> None:

@@ -88,7 +88,10 @@ class _Replica:
         self.last_step_s: float | None = None
         self.last_probe_s: float | None = None
         self.acct = StepAccounting()
-        self._work = None          # (Decision, [(request, image idx), ...])
+        self.batches = 0           # batches placed here: the next batch id
+        # (Decision, [(request, image idx), ...], batch id, when the batch's
+        #  first span began, when it was placed); the times only if traced
+        self._work = None
         self.thread: threading.Thread | None = None
 
     @property
@@ -293,6 +296,8 @@ class ServeFleet:
             raise
 
     def _dispatch_loop(self) -> None:
+        tr = self.tracer
+        t_hold = None     # tracing only: when the scheduler first said wait
         while True:
             with self._cv:
                 while True:
@@ -307,6 +312,13 @@ class ServeFleet:
                         now_s=now, draining=self._closing, busy=busy)
                     if d.action == "dispatch":
                         break
+                    if tr.enabled:
+                        # a wait for the batching window or for a free
+                        # replica holds the batch; an empty queue drops it
+                        if d.action != "wait":
+                            t_hold = None
+                        elif t_hold is None:
+                            t_hold = now
                     if self._closing and d.action == "idle":
                         # queue drained; once in-flight steps land, stop
                         if all(r._work is None for r in self.replicas):
@@ -321,19 +333,27 @@ class ServeFleet:
                 work = [self._queue.popleft()
                         for _ in range(min(d.rows, len(self._queue)))]
                 rep = self.replicas[d.replica]
-                rep._work = (d, work)
-                tr = self.tracer
+                bid = rep.batches
+                rep.batches += 1
+                t_first = t_pop = None
                 if tr.enabled:
                     t_pop = self._clock()
+                    t_first = now
+                    if t_hold is not None:
+                        tr.span("batch", "hold", t0=t_hold, t1=now,
+                                replica=d.replica, batch=bid)
+                        t_first, t_hold = t_hold, None
                     tr.span("batch", "place", t0=now, t1=t_pop,
                             bucket=d.bucket, replica=d.replica,
-                            value=len(work))
+                            value=len(work), batch=bid)
                     tr.counter("queue_depth", len(self._queue), t=t_pop)
                     for r, _ in work:
                         if not r.t_dequeue:    # first image leaves queue
                             r.t_dequeue = t_pop
                             tr.span("request", "queue", t0=r.t_submit,
-                                    t1=t_pop, rid=r.rid, replica=d.replica)
+                                    t1=t_pop, rid=r.rid, replica=d.replica,
+                                    batch=bid)
+                rep._work = (d, work, bid, t_first, t_pop)
                 self._cv.notify_all()
 
     # -- replica workers ----------------------------------------------------
@@ -347,6 +367,8 @@ class ServeFleet:
 
     def _replica_loop(self, rep: _Replica) -> None:
         pace = self.pace_fps
+        tr = self.tracer
+        t_mark = None     # tracing only: where this replica's last span ended
         while True:
             with self._cv:
                 while rep._work is None and not self._stopping \
@@ -356,20 +378,29 @@ class ServeFleet:
                     rep.state = STOPPED
                     self._cv.notify_all()
                     return
-                d, work = rep._work
+                d, work, bid, t_first, t_pop = rep._work
                 model = rep.model
             # model step OUTSIDE the lock: other replicas keep running
-            tr = self.tracer
+            if tr.enabled and t_mark is not None and t_first > t_mark:
+                # waited for work until its batch's hold (or place) began
+                tr.span("worker", "idle", t0=t_mark, t1=t_first,
+                        replica=rep.idx)
             try:
                 t_start = self._clock()
                 batch, _ = assemble_batch(
                     [req.images[i] for req, i in work], d.bucket)
+                if tr.enabled:
+                    t_occ = self._clock()
                 occ = batch_occupancy(batch[:len(work)])  # real rows only
                 t0 = self._clock()
                 if tr.enabled:
-                    tr.span("batch", "assemble", t0=t_start, t1=t0,
+                    # from the hand-off: the wake-up is the replica's too
+                    tr.span("batch", "assemble", t0=t_pop, t1=t0,
                             bucket=d.bucket, replica=rep.idx,
-                            occupancy=occ, value=len(work))
+                            occupancy=occ, value=len(work), batch=bid)
+                    tr.span("batch", "occupancy", t0=t_occ, t1=t0,
+                            bucket=d.bucket, replica=rep.idx,
+                            occupancy=occ, batch=bid)
                 logits = np.asarray(model.step(batch))
                 if pace is not None:
                     # emulated fixed-rate core: the slot is held for the
@@ -381,10 +412,11 @@ class ServeFleet:
                 if tr.enabled:
                     tr.span("batch", "step", t0=t0, t1=t0 + busy_s,
                             bucket=d.bucket, replica=rep.idx,
-                            occupancy=occ, value=len(work))
+                            occupancy=occ, value=len(work), batch=bid)
                     tr.counter("occupancy", occ, t=t0, replica=rep.idx)
             except Exception as exc:
                 self._fail_batch(rep, work, exc)
+                t_mark = None     # the failed batch's time has no span
                 continue
             labels = logits[:len(work)].argmax(axis=-1)
             now = self._clock()
@@ -411,7 +443,8 @@ class ServeFleet:
                         self.latency_hist.observe(now - req.t_submit)
                         if tr.enabled:
                             tr.span("request", "complete", t0=req.t_submit,
-                                    t1=now, rid=req.rid, replica=rep.idx)
+                                    t1=now, rid=req.rid, replica=rep.idx,
+                                    batch=bid)
                 wall_s = self._clock() - t_start
                 self.acct.record_step(rows=len(work), bucket=d.bucket,
                                       busy_s=busy_s, wall_s=wall_s,
@@ -434,6 +467,11 @@ class ServeFleet:
                         pass   # a streaming callback must not kill serving
             for req in completed:
                 self._complete_safely(req.future, result=list(req.labels))
+            if tr.enabled:
+                t_mark = self._clock()
+                tr.span("batch", "finish", t0=t0 + busy_s, t1=t_mark,
+                        bucket=d.bucket, replica=rep.idx, value=len(work),
+                        batch=bid)
 
     # -- failure containment (same semantics as the runtime) ----------------
 

@@ -46,6 +46,10 @@ from ..obs.metrics import LatencyHistogram
 from ..obs.trace import NULL_TRACER
 from .scheduler import ContinuousBatchingScheduler, QueueFull, ServePolicy
 
+# the worker's waiting phase for each scheduler decision that is not
+# "dispatch"
+_WAITING = {"idle": "idle", "wait": "hold"}
+
 
 @dataclasses.dataclass
 class AsyncRequest(Request):
@@ -74,6 +78,11 @@ class AsyncServeRuntime:
     On completion a request's image payload is released (its ``labels``,
     timing, and image COUNT survive) — a long-lived server keeps serving
     history for ``stats()``, not every pixel it ever classified.
+
+    With a ``tracer`` the worker's loop is a gap-free sequence of spans
+    (``idle``, ``hold``, ``place``, ``assemble`` with ``occupancy`` inside,
+    ``step``, ``finish``; ``repro.obs`` README) and every span of a batch,
+    and each request's ``queue``/``complete`` span, carries the batch id.
     """
 
     def __init__(self, model, *, policy: ServePolicy | None = None,
@@ -259,6 +268,12 @@ class AsyncServeRuntime:
             raise
 
     def _worker_loop(self) -> None:
+        tr = self.tracer
+        bid = 0                  # the next batch's id
+        # tracing only: the worker's spans tile its timeline. ``t_mark`` is
+        # where its last span ended, ``waiting`` the waiting phase open
+        # since then ("idle": empty queue; "hold": the scheduler said wait)
+        t_mark, waiting = None, None
         while True:
             with self._cv:
                 while True:
@@ -268,44 +283,63 @@ class AsyncServeRuntime:
                     d = self.scheduler.decide(
                         backlog=len(self._queue), oldest_submit_s=oldest,
                         now_s=now, draining=self._closing)
+                    if tr.enabled:
+                        if t_mark is None:
+                            t_mark = now
+                        phase = _WAITING.get(d.action)
+                        if phase != waiting:
+                            if waiting is not None:
+                                self._trace_wait(waiting, t_mark, now, bid)
+                                t_mark = now
+                            waiting = phase
                     if d.action == "dispatch":
                         break
                     if self._closing:      # idle + closing: queue is drained
+                        if tr.enabled:
+                            self._trace_wait(waiting, t_mark, now, bid)
                         return
                     # "idle": sleep until a submit; "wait": until the window
                     # deadline (a submit may re-open a better decision first)
                     self._cv.wait(d.wait_s if d.action == "wait" else None)
                 work = [self._queue.popleft()
                         for _ in range(min(d.rows, len(self._queue)))]
-                tr = self.tracer
                 if tr.enabled:
                     t_pop = self._clock()
-                    tr.span("batch", "place", t0=now, t1=t_pop,
-                            bucket=d.bucket, value=len(work))
+                    tr.span("batch", "place", t0=t_mark, t1=t_pop,
+                            bucket=d.bucket, value=len(work), batch=bid)
                     tr.counter("queue_depth", len(self._queue), t=t_pop)
                     for req, _ in work:
                         if not req.t_dequeue:   # first image leaves queue
                             req.t_dequeue = t_pop
                             tr.span("request", "queue", t0=req.t_submit,
-                                    t1=t_pop, rid=req.rid)
+                                    t1=t_pop, rid=req.rid, batch=bid)
+                    t_mark = t_pop
             # model step OUTSIDE the lock: submits stay concurrent
             try:
                 t_start = self._clock()
                 batch, _ = assemble_batch([req.images[i] for req, i in work],
                                           d.bucket)
+                if tr.enabled:
+                    t_occ = self._clock()
                 occ = batch_occupancy(batch[:len(work)])  # real rows only
                 t0 = self._clock()
                 if tr.enabled:
-                    tr.span("batch", "assemble", t0=t_start, t1=t0,
-                            bucket=d.bucket, occupancy=occ, value=len(work))
+                    tr.span("batch", "assemble", t0=t_mark, t1=t0,
+                            bucket=d.bucket, occupancy=occ, value=len(work),
+                            batch=bid)
+                    tr.span("batch", "occupancy", t0=t_occ, t1=t0,
+                            bucket=d.bucket, occupancy=occ, batch=bid)
                 logits = np.asarray(self.model.step(batch))
                 busy_s = self._clock() - t0
                 if tr.enabled:
                     tr.span("batch", "step", t0=t0, t1=t0 + busy_s,
-                            bucket=d.bucket, occupancy=occ, value=len(work))
+                            bucket=d.bucket, occupancy=occ, value=len(work),
+                            batch=bid)
                     tr.counter("occupancy", occ, t=t0)
             except Exception as exc:
                 self._fail_batch(work, exc)
+                bid += 1
+                t_mark = None     # the failed batch's time has no span
                 continue
             labels = logits[:len(work)].argmax(axis=-1)
             now = self._clock()
@@ -329,7 +363,7 @@ class AsyncServeRuntime:
                         self.latency_hist.observe(now - req.t_submit)
                         if tr.enabled:
                             tr.span("request", "complete", t0=req.t_submit,
-                                    t1=now, rid=req.rid)
+                                    t1=now, rid=req.rid, batch=bid)
                 self.acct.record_step(rows=len(work), bucket=d.bucket,
                                       busy_s=busy_s,
                                       wall_s=self._clock() - t_start,
@@ -344,6 +378,20 @@ class AsyncServeRuntime:
                         pass   # a streaming callback must not kill serving
             for req in completed:
                 self._complete_safely(req.future, result=list(req.labels))
+            if tr.enabled:
+                t_mark = self._clock()
+                tr.span("batch", "finish", t0=t0 + busy_s, t1=t_mark,
+                        bucket=d.bucket, value=len(work), batch=bid)
+            bid += 1
+
+    def _trace_wait(self, phase: str, t0: float, t1: float,
+                    bid: int) -> None:
+        """One stretch of waiting: ``idle`` (empty queue) or ``hold`` (a
+        batch held open for batch ``bid``)."""
+        if phase == "hold":
+            self.tracer.span("batch", "hold", t0=t0, t1=t1, batch=bid)
+        else:
+            self.tracer.span("worker", "idle", t0=t0, t1=t1)
 
     # -- accounting ---------------------------------------------------------
 

@@ -18,7 +18,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.spikformer import SpikformerConfig, init, fold_inference_params
+from repro.core.spikformer import (SpikformerConfig, init,
+                                   fold_inference_params, layer_paths)
 from repro.infer import (CompiledModel, ExecutionPlan, MicroBatchEngine,
                          Request, backend_spec, compile as infer_compile,
                          list_backends, quantize_weights, register_backend,
@@ -326,6 +327,31 @@ def test_compile_packed_matches_reference_across_buckets(small, t,
     want = np.asarray(packed.classify(big)).tolist()
     assert [int(x) for x in done[0].labels] == want[:2]
     assert [int(x) for x in done[1].labels] == want
+
+
+@pytest.mark.parametrize("backend,options", [
+    ("packed", {}),                              # fc1 and fc2 apart
+    ("packed_pallas", {"interpret": True}),      # the fused MLP pair
+], ids=["packed", "pallas_fused_mlp"])
+def test_jitted_forward_names_every_layer_scope(backend, options):
+    """The compiled forward runs each layer under a ``jax.named_scope`` of
+    its path (``layer_paths``; the fused MLP pair under its block's
+    ``mlp``), the token reshape under ``tokens`` and the readout under
+    ``head``: the lowered program's locations carry every one."""
+    cfg = SpikformerConfig().scaled(img_size=16, dim=32, depth=2)
+    model = infer_compile(init(jax.random.PRNGKey(0), cfg), cfg,
+                          ExecutionPlan(backend=backend,
+                                        backend_options=options,
+                                        batch_buckets=(2,)))
+    text = model._fwd.lower(
+        model.folded, jnp.zeros(model.input_shape(2), jnp.uint8)
+    ).as_text(debug_info=True)
+    fused = backend == "packed_pallas"
+    for path in layer_paths(cfg) + ["tokens", "head"]:
+        if fused and "/mlp/" in path:
+            assert f"/{path}/" not in text, path
+            path = path.rsplit("/", 1)[0]
+        assert f"/{path}/" in text, path
 
 
 def test_compiled_step_rejects_non_bucket_batch(small):

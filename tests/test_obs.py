@@ -11,12 +11,17 @@ Four standards of proof, mirroring the serving tests:
 * the span CHAIN is client-invariant: the same request trace through
   the sync engine, the async runtime, and a 2-replica fleet yields the
   identical per-rid lifecycle chain (timestamps differ, structure may
-  not);
+  not), and every request's spans name a batch that stepped;
+* the runtime worker's spans TILE its timeline under a fake clock:
+  idle -> hold -> place -> assemble (occupancy inside) -> step -> finish,
+  each boundary shared exactly;
 * EXPORT round-trips: the JSONL loader inverts the writer bit-exactly
   and refuses wrong-kind/wrong-version/truncated files loudly.
 """
+import itertools
 import json
 import pathlib
+import re
 import sys
 
 import jax
@@ -27,7 +32,7 @@ from repro.core.spikformer import SpikformerConfig, init
 from repro.events import EventStream, EventStreamSession
 from repro.infer import (ExecutionPlan, MicroBatchEngine,
                          QueueDepthWatermark, SERVE_STATS_VERSION,
-                         compile as infer_compile, profile_layer_paths)
+                         compile as infer_compile)
 from repro.infer.engine import (Request, StepAccounting, latency_summary,
                                 serve_stats)
 from repro.obs import (LIFECYCLE, Counter, Gauge, LatencyHistogram,
@@ -121,6 +126,7 @@ def test_null_tracer_is_inert():
     assert NULL_TRACER.dropped_spans == 0
     assert LIFECYCLE == ("admit", "queue", "place", "assemble", "step",
                          "complete")
+    assert NULL_TRACER.anchor is None
 
 
 # ---------------------------------------------------------------------------
@@ -235,17 +241,17 @@ def test_engine_span_table_pinned(small):
     eng = MicroBatchEngine(model, tracer=tr, clock=FakeClock())
     eng.submit(imgs[:2])
     eng.run()
-    table = [(s.category, s.name, s.t0, s.t1, s.rid, s.bucket)
+    table = [(s.category, s.name, s.t0, s.t1, s.rid, s.bucket, s.batch)
              for s in tr.spans()]
     assert table == [
-        ("request", "admit", 1.0, 2.0, 0, None),
-        ("counter", "queue_depth", 2.0, 2.0, None, None),
-        ("batch", "place", 3.0, 4.0, None, 2),
-        ("request", "queue", 2.0, 5.0, 0, None),
-        ("batch", "assemble", 5.0, 6.0, None, 2),
-        ("batch", "step", 6.0, 7.0, None, 2),
-        ("counter", "occupancy", 6.0, 6.0, None, None),
-        ("request", "complete", 2.0, 8.0, 0, None),
+        ("request", "admit", 1.0, 2.0, 0, None, None),
+        ("counter", "queue_depth", 2.0, 2.0, None, None, None),
+        ("batch", "place", 3.0, 4.0, None, 2, 0),
+        ("request", "queue", 2.0, 5.0, 0, None, 0),
+        ("batch", "assemble", 5.0, 6.0, None, 2, 0),
+        ("batch", "step", 6.0, 7.0, None, 2, 0),
+        ("counter", "occupancy", 6.0, 6.0, None, None, None),
+        ("request", "complete", 2.0, 8.0, 0, None, 0),
     ]
     by_name = {s.name: s for s in tr.spans()}
     assert by_name["admit"].value == 2          # images admitted
@@ -323,6 +329,130 @@ def test_request_chains_identical_across_clients(small):
     for tr in (tr_eng, tr_rt, tr_fl):
         assert tr.dropped_spans == 0
         assert trace_report.check_complete(tr.spans(), 0) == []
+
+
+def serve_sizes(client, sizes):
+    """Submit ``sizes`` images per request through a ServeClient and wait
+    for every result."""
+    handles = [client.submit(np.zeros((n, 16, 16, 3), np.uint8), rid=k)
+               for k, n in enumerate(sizes)]
+    if isinstance(client, MicroBatchEngine):
+        client.run()
+    for h in handles:
+        h.result(timeout=60.0)
+
+
+@pytest.mark.parametrize("client", ["engine", "runtime", "fleet"])
+def test_request_spans_name_a_batch_that_stepped(small, client):
+    """Every ``queue`` and ``complete`` span names the batch (by replica
+    and batch id) that took the request's first image and the one that
+    finished it; both batches have a ``step`` span, and the first was
+    placed no later than the last."""
+    _, model, _ = small
+    tr = Tracer()
+    if client == "engine":
+        serve_sizes(MicroBatchEngine(model, tracer=tr), [2, 1, 3, 2, 5])
+    elif client == "runtime":
+        with AsyncServeRuntime(model, tracer=tr) as rt:
+            serve_sizes(rt, [2, 1, 3, 2, 5])
+    else:
+        with ServeFleet(model, replicas=2, tracer=tr) as fleet:
+            serve_sizes(fleet, [2, 1, 3, 2, 5])
+    spans = tr.spans()
+    steps = {(s.replica, s.batch) for s in spans
+             if s.category == "batch" and s.name == "step"}
+    placed = {(s.replica, s.batch): s.t0 for s in spans
+              if s.category == "batch" and s.name == "place"}
+    assert all(b is not None for _, b in steps) and set(placed) == steps
+    linked = {}
+    for s in spans:
+        if s.category == "request" and s.name in ("queue", "complete"):
+            assert (s.replica, s.batch) in steps, s
+            linked.setdefault(s.rid, {})[s.name] = placed[s.replica, s.batch]
+    assert sorted(linked) == [0, 1, 2, 3, 4]
+    for rid, by in linked.items():
+        assert by["queue"] <= by["complete"], rid
+    # a batch's own spans carry its id too
+    for s in spans:
+        if s.category == "batch":
+            assert (s.replica, s.batch) in steps, s
+    assert trace_report.check_complete(spans, tr.dropped_spans) == []
+
+
+class TickClock:
+    """A fake clock safe across threads: each read advances 1 ms."""
+
+    def __init__(self):
+        self._ticks = itertools.count(1)
+
+    def __call__(self):
+        return next(self._ticks) * 1e-3
+
+
+class BucketFourModel:
+    """CompiledModel stand-in with one bucket of four 4x4 images."""
+    buckets = (4,)
+
+    def input_shape(self, bucket=None):
+        return (bucket or 4, 4, 4, 3)
+
+    def step(self, batch):
+        return np.zeros((len(batch), 10), np.float32)
+
+
+def worker_timeline(spans):
+    return sorted((s for s in spans
+                   if s.category in ("worker", "batch")
+                   and s.name != "occupancy"), key=lambda s: s.t0)
+
+
+def test_runtime_worker_spans_tile_its_timeline():
+    """On a fake clock: one image waits out a 10 ms window (one ``hold``
+    however often the worker wakes), four fill the bucket at once (no
+    hold), and the worker's spans tile its timeline with shared
+    boundaries: idle, hold, place, assemble (occupancy inside), step,
+    finish."""
+    tr = Tracer(capacity=256)
+    rt = AsyncServeRuntime(BucketFourModel(),
+                           policy=ServePolicy(max_wait_ms=10.0), tracer=tr)
+    rt._clock = TickClock()       # the serving clock, made deterministic
+    with rt:
+        img = np.zeros((1, 4, 4, 3), np.uint8)
+        rt.submit(img).result(timeout=30)
+        rt.submit(np.repeat(img, 4, axis=0)).result(timeout=30)
+    spans = tr.spans()
+    line = worker_timeline(spans)
+    names = " ".join(s.name for s in line)
+    assert re.fullmatch(r"(idle )?hold place assemble step finish "
+                        r"(idle )?place assemble step finish idle", names), \
+        names
+    for a, b in zip(line, line[1:]):
+        assert a.t1 == b.t0, (a, b)          # no gap, no overlap
+        assert a.t0 <= a.t1
+    first_finish = next(s for s in line if s.name == "finish")
+    for s in line:
+        want = None if s.name == "idle" else int(s.t0 > first_finish.t0)
+        assert s.batch == want, s
+    occ = [s for s in spans
+           if s.category == "batch" and s.name == "occupancy"]
+    asm = {s.batch: s for s in line if s.name == "assemble"}
+    assert [s.batch for s in occ] == [0, 1]
+    for o in occ:
+        assert asm[o.batch].t0 <= o.t0 <= o.t1 == asm[o.batch].t1
+    req = {(s.rid, s.name): s.batch for s in spans if s.category == "request"}
+    assert req[0, "queue"] == req[0, "complete"] == 0
+    assert req[1, "queue"] == req[1, "complete"] == 1
+    assert tr.dropped_spans == 0
+
+
+def test_untraced_runtime_and_fleet_stay_silent(small):
+    _, model, imgs = small
+    with AsyncServeRuntime(model) as rt:
+        rt.submit(imgs[:3]).result(timeout=60)
+    with ServeFleet(model, replicas=2) as fleet:
+        fleet.submit(imgs[:3]).result(timeout=60)
+    assert rt.tracer is NULL_TRACER and fleet.tracer is NULL_TRACER
+    assert len(NULL_TRACER) == 0
 
 
 def test_queue_depth_peak_parity_engine_vs_runtime(small):
@@ -446,13 +576,13 @@ def test_serve_stats_empty_window_reports_absence():
 def traced_fixture():
     tr = Tracer(capacity=32)
     tr.span("request", "admit", t0=10.0, t1=10.1, rid=0, value=2)
-    tr.span("request", "queue", t0=10.1, t1=10.3, rid=0)
-    tr.span("batch", "place", t0=10.1, t1=10.2, bucket=2)
+    tr.span("request", "queue", t0=10.1, t1=10.3, rid=0, batch=0)
+    tr.span("batch", "place", t0=10.1, t1=10.2, bucket=2, batch=0)
     tr.span("batch", "step", t0=10.3, t1=10.9, bucket=2, occupancy=0.4,
-            value=2, replica=1)
+            value=2, replica=1, batch=0)
     tr.span("window", "encode", t0=10.0, t1=10.05, rid=3, value=7)
     tr.counter("queue_depth", 2, t=10.1)
-    tr.span("request", "complete", t0=10.1, t1=11.0, rid=0)
+    tr.span("request", "complete", t0=10.1, t1=11.0, rid=0, batch=0)
     return tr
 
 
@@ -476,6 +606,8 @@ def test_chrome_trace_structure():
     assert by_name["place"]["tid"] == 1           # scheduler lane
     assert by_name["encode"]["tid"] == 10 + 3     # rid lane wins over window
     assert by_name["step"]["args"]["occupancy"] == 0.4
+    assert by_name["step"]["args"]["batch"] == 0
+    assert "batch" not in by_name["admit"]["args"]
     assert counters[0]["args"] == {"queue_depth": 2.0}
     proc_names = {e["pid"]: e["args"]["name"] for e in meta
                   if e["name"] == "process_name"}
@@ -493,11 +625,39 @@ def test_jsonl_round_trip(tmp_path):
     assert header["kind"] == "repro.obs.spans"
     assert header["spans_version"] == SPANS_SCHEMA_VERSION
     assert header["dropped_spans"] == 0 and header["meta"] == {"mode": "test"}
+    assert header["clock_anchor"] == {"perf_counter_s": tr.anchor[0],
+                                      "time_ns": tr.anchor[1]}
     assert spans == tr.spans()                    # bit-exact inversion
-    # the perfetto writer emits valid JSON alongside
+    assert [s.batch for s in spans if s.name in ("queue", "complete")] \
+        == [0, 0]
+    # the perfetto writer emits valid JSON alongside, anchor included
     pf = tmp_path / "trace.perfetto.json"
     assert write_chrome_trace(pf, tr) == 7
-    assert len(json.loads(pf.read_text())["traceEvents"]) > 7
+    doc = json.loads(pf.read_text())
+    assert len(doc["traceEvents"]) > 7
+    assert doc["otherData"]["clock_anchor"] == header["clock_anchor"]
+    assert doc["otherData"]["t_base_s"] == 10.0
+
+
+def test_tracer_clock_anchor_pairs_perf_counter_with_real_time():
+    import time
+    before_pc, before_ns = time.perf_counter(), time.time_ns()
+    tr = Tracer(capacity=4)
+    after_pc, after_ns = time.perf_counter(), time.time_ns()
+    pc, ns = tr.anchor
+    assert before_pc <= pc <= after_pc and before_ns <= ns <= after_ns
+
+
+def test_jsonl_loader_refuses_version_1(tmp_path):
+    old = tmp_path / "v1.jsonl"
+    old.write_text(json.dumps({"kind": "repro.obs.spans", "spans_version": 1,
+                               "spans": 1, "dropped_spans": 0}) + "\n"
+                   + json.dumps({"cat": "request", "name": "admit",
+                                 "t0": 0.0, "t1": 0.1, "rid": 0,
+                                 "replica": None, "bucket": None,
+                                 "occ": None, "value": 1}) + "\n")
+    with pytest.raises(ValueError, match="spans_version=1.*batch ids"):
+        load_spans_jsonl(old)
 
 
 def test_jsonl_loader_refuses_bad_files(tmp_path):
@@ -540,62 +700,46 @@ def test_trace_report_views():
 
 def test_trace_report_gate_catches_violations():
     ok = [Span("request", "admit", 0.0, 0.1, rid=0, value=2),
-          Span("request", "queue", 0.1, 0.2, rid=0),
-          Span("request", "complete", 0.1, 0.3, rid=0),
+          Span("request", "queue", 0.1, 0.2, rid=0, batch=0),
+          Span("request", "complete", 0.1, 0.3, rid=0, batch=0),
           Span("request", "admit", 0.0, 0.1, rid=1, value=0),
-          Span("request", "complete", 0.1, 0.1, rid=1)]
+          Span("request", "complete", 0.1, 0.1, rid=1),
+          Span("batch", "step", 0.2, 0.3, batch=0)]
     assert trace_report.check_complete(ok, 0) == []
     assert trace_report.check_complete(ok, dropped_spans=5)  # lossy: fails
-    missing = ok[:2]                                  # admitted, never done
+    missing = [ok[0], ok[1], ok[5]]                   # admitted, never done
     problems = trace_report.check_complete(missing, 0)
     assert len(problems) == 1 and "complete" in problems[0]
     # a non-empty admit with no queue span is a broken chain too
-    no_queue = [ok[0], ok[2]]
+    no_queue = [ok[0], ok[2], ok[5]]
     assert any("queue" in p for p in trace_report.check_complete(no_queue, 0))
+    # a request whose batch never stepped, or whose span names no batch
+    no_step = trace_report.check_complete(ok[:5], 0)
+    assert len(no_step) == 2 and all("no step" in p for p in no_step)
+    unlinked = [ok[0], ok[1]._replace(batch=None), ok[2], ok[5]]
+    assert any("batch None" in p
+               for p in trace_report.check_complete(unlinked, 0))
 
 
-def test_trace_report_main_gate(tmp_path):
+def test_trace_report_main_gate(tmp_path, capsys):
     tr = Tracer()
     tr.span("request", "admit", t0=0.0, t1=0.1, rid=0, value=1)
-    tr.span("request", "queue", t0=0.1, t1=0.2, rid=0)
-    tr.span("request", "complete", t0=0.1, t1=0.4, rid=0)
+    tr.span("request", "queue", t0=0.1, t1=0.2, rid=0, batch=0)
+    tr.span("batch", "hold", t0=0.1, t1=0.2, batch=0)
+    tr.span("batch", "step", t0=0.2, t1=0.3, batch=0)
+    tr.span("request", "complete", t0=0.1, t1=0.4, rid=0, batch=0)
     good = tmp_path / "good.jsonl"
     write_spans_jsonl(good, tr)
     assert trace_report.main([str(good), "--assert-complete"]) == 0
+    out = capsys.readouterr().out
+    assert re.search(r"rid=0 .* batch=0 hold=100\.000ms .* "
+                     r"step=100\.000ms", out), out
     tr2 = Tracer()
     tr2.span("request", "admit", t0=0.0, t1=0.1, rid=0, value=1)
     bad = tmp_path / "bad.jsonl"
     write_spans_jsonl(bad, tr2)
     assert trace_report.main([str(bad), "--assert-complete"]) == 1
     assert trace_report.main([str(bad)]) == 0         # report-only never gates
-
-
-# ---------------------------------------------------------------------------
-# per-layer kernel timing: CompiledModel.profile_step
-# ---------------------------------------------------------------------------
-
-def test_profile_step_rows_cover_every_layer(small):
-    cfg, model, imgs = small
-    tr = Tracer()
-    rows = model.profile_step(imgs[:2], tracer=tr)
-    assert [r["path"] for r in rows] == profile_layer_paths(cfg)
-    assert all(r["seconds"] >= 0.0 for r in rows)
-    routes = model.plan.routes or {}
-    for r in rows:
-        default = "stdp" if r["path"].endswith("/stdp") else "unpack"
-        assert r["route"] == routes.get(r["path"], default)
-        assert r["route"] in ("lut", "lut_sparse", "unpack", "stdp")
-    layer_spans = [s for s in tr.spans() if s.category == "layer"]
-    assert [s.name for s in layer_spans] == [r["path"] for r in rows]
-    assert all(s.value == pytest.approx(s.duration_s) for s in layer_spans)
-
-
-def test_profile_step_default_batch_and_bad_batch(small):
-    _, model, imgs = small
-    rows = model.profile_step()                   # zeros at the first bucket
-    assert len(rows) == len(profile_layer_paths(model.cfg))
-    with pytest.raises(ValueError, match="bucket"):
-        model.profile_step(imgs[:3])              # 3 is not a bucket
 
 
 # ---------------------------------------------------------------------------
