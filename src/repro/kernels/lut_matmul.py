@@ -321,6 +321,21 @@ class RouteConstants:
     property of the *host*, not the model — ``scripts/autotune_routes.py``
     refits them from timings and an ``ExecutionPlan`` carries them as data,
     so a committed plan pins the dispatch decisions it was tuned for.
+
+    The two ``pallas_*`` constants (``choose_pallas_route``) are a TPU v5e
+    fit, in units of one Pallas unpack-dot FMA as that model counts them
+    (t live planes x M x K x N). They come from device traces of
+    Spikformer-8-512 with int8 weights at published widths on one v5e,
+    where 35 layers took the gather and 17 the dot: at T=4, bucket 32,
+    ``lut_gather_matmul`` spent 521.7 ms on 30.0 G selected elements
+    (17.4 ps each) and ``spike_matmul`` 17.3 ms on 434 G FMAs (0.040 ps
+    each), a ratio of 436; at T=16, bucket 8, 18.1 ps against 0.021 ps,
+    873. The lower ratio is the default. The gather loses wherever
+    ``pallas_gather_cost`` exceeds ~8x ``pallas_dot_cost`` (one selected
+    element stands for 8 K-rows), so every shape of that network takes
+    the dot at every batch size. The index-build term reuses the CPU
+    ``transpose_cost``, far below the ~1270 ps a byte the same traces
+    show for it, which only flatters the gather.
     """
     gather_cost: float = 4.0     # per gathered table element
     transpose_cost: float = 2.5  # per packed input byte
@@ -331,10 +346,10 @@ class RouteConstants:
     compact_cost: float = 40.0   # sparse route: per (index byte x slot)
                                  # compaction element (cumsum + one-hot
                                  # select; N-independent, int32-bound)
-    pallas_gather_cost: float = 2.0  # pallas route: per gathered table
-                                     # element (one-hot MXU select row)
-    pallas_dot_cost: float = 1.0     # pallas route: per unpack-dot FMA
-                                     # (8 planes folded into one MXU dot)
+    pallas_gather_cost: float = 436.0  # pallas route: per gathered table
+                                       # element (one-hot MXU select row)
+    pallas_dot_cost: float = 1.0       # pallas route: per unpack-dot FMA
+                                       # (8 planes folded into one MXU dot)
 
     def to_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -414,8 +429,12 @@ def choose_pallas_route(*, m: int, k: int, n: int, g: int, t: int,
     builds the index bytes — while the unpack route folds all 8 planes of
     a group into the row dim of one MXU dot (t*M*K*N FMAs, no unpack
     writes: the bits expand in-register inside the kernel). The constants
-    (``pallas_gather_cost`` / ``pallas_dot_cost``) are host/device
-    properties; ``scripts/autotune_routes.py --pallas`` refits them.
+    (``pallas_gather_cost`` / ``pallas_dot_cost``) are device properties.
+    The defaults are the TPU v5e fit from device traces (``RouteConstants``
+    says which): a selected element costs ~436 dot FMAs there, so every
+    layer whose table fits the cap still takes the dot — the gather wins
+    only under constants that price it below ~8 FMAs.
+    ``scripts/autotune_routes.py --pallas`` refits them on another chip.
 
     ``occupancy`` is accepted for signature parity with ``choose_route``
     and ignored: the dense Pallas gather has no zero-chunk skipping (a

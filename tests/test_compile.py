@@ -32,6 +32,13 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent
                        / "scripts"))
 
 
+# the Pallas cost model's constants before the v5e fit: cheap enough that
+# the gather wins where its table fits (fc1 and fc2 of the scaled model
+# too, which the fused MLP pair needs)
+CHEAP_PALLAS_GATHER = RouteConstants(pallas_gather_cost=2.0,
+                                     pallas_dot_cost=1.0)
+
+
 def exact(a, b):
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
@@ -101,7 +108,8 @@ def test_packed_pallas_backend_registered_and_compiles(small):
     kind (enforced: a CPU host needs the interpret escape hatch), and —
     capability-declared — gets REAL (C,256,N) gather tables built into
     its LUT-planned layers: the Pallas byte-LUT kernel consumes them from
-    VMEM."""
+    VMEM. The v5e defaults send every layer to the dot, so the plan prices
+    the gather cheap enough for the cost model to pick it."""
     cfg, params, _ = small
     spec = backend_spec("packed_pallas")
     assert backend_spec("pallas").name == "packed_pallas"   # alias resolves
@@ -112,7 +120,8 @@ def test_packed_pallas_backend_registered_and_compiles(small):
 
     model = infer_compile(params, cfg,
                           ExecutionPlan(backend="pallas", batch_buckets=(2,),
-                                        backend_options={"interpret": True}))
+                                        backend_options={"interpret": True},
+                                        route_constants=CHEAP_PALLAS_GATHER))
     assert model.backend.pallas is True
     assert model.plan.routes                   # planning ran
     luts = [p for p, r in model.plan.routes.items() if r == "lut"]
@@ -329,11 +338,12 @@ def test_compile_packed_matches_reference_across_buckets(small, t,
     assert [int(x) for x in done[1].labels] == want
 
 
-@pytest.mark.parametrize("backend,options", [
-    ("packed", {}),                              # fc1 and fc2 apart
-    ("packed_pallas", {"interpret": True}),      # the fused MLP pair
+@pytest.mark.parametrize("backend,options,constants", [
+    ("packed", {}, RouteConstants()),            # fc1 and fc2 apart
+    ("packed_pallas", {"interpret": True},       # the fused MLP pair
+     CHEAP_PALLAS_GATHER),
 ], ids=["packed", "pallas_fused_mlp"])
-def test_jitted_forward_names_every_layer_scope(backend, options):
+def test_jitted_forward_names_every_layer_scope(backend, options, constants):
     """The compiled forward runs each layer under a ``jax.named_scope`` of
     its path (``layer_paths``; the fused MLP pair under its block's
     ``mlp``), the token reshape under ``tokens`` and the readout under
@@ -342,7 +352,8 @@ def test_jitted_forward_names_every_layer_scope(backend, options):
     model = infer_compile(init(jax.random.PRNGKey(0), cfg), cfg,
                           ExecutionPlan(backend=backend,
                                         backend_options=options,
-                                        batch_buckets=(2,)))
+                                        batch_buckets=(2,),
+                                        route_constants=constants))
     text = model._fwd.lower(
         model.folded, jnp.zeros(model.input_shape(2), jnp.uint8)
     ).as_text(debug_info=True)

@@ -31,6 +31,10 @@ from repro.kernels import ops
 from repro.kernels import lut_matmul as lut
 
 AWKWARD_TS = [1, 9, 17]
+# the Pallas cost model's constants before the v5e fit: a gathered element
+# at 2 dot FMAs, cheap enough that the gather wins where its table fits
+CHEAP_PALLAS_GATHER = lut.RouteConstants(pallas_gather_cost=2.0,
+                                         pallas_dot_cost=1.0)
 
 
 def exact(a, b):
@@ -245,13 +249,14 @@ def test_choose_route_picks_lut_at_bench_layer_shapes():
 
 def _compiled(params, cfg, *, backend="packed", batch_size=2,
               weight_dtype=None, route="auto", folded=False, pallas=None,
-              jit=True):
+              jit=True, route_constants=lut.DEFAULT_ROUTE_CONSTANTS):
     """One-bucket compile() under the historical session argument names —
     keeps the parity tests reading like serving call sites."""
     options = {} if pallas is None else {"pallas": pallas}
     plan = ExecutionPlan(backend=backend, weight_dtype=weight_dtype,
                          batch_buckets=(int(batch_size),), route=route,
-                         backend_options=options)
+                         backend_options=options,
+                         route_constants=route_constants)
     return infer_compile(params, cfg, plan, folded=folded, jit=jit)
 
 
@@ -349,11 +354,14 @@ def test_reference_skips_and_pallas_builds_tables():
     """The table capability follows who gathers: the float reference never
     does (its LUT layers carry a cheap boolean plan flag), while a
     Pallas-pinned packed model DOES — its byte-LUT kernel gathers the
-    (C,256,N) tables from VMEM, so planning must build them."""
+    (C,256,N) tables from VMEM, so planning must build them. The v5e
+    defaults route every layer to the dot, so the Pallas side prices the
+    gather cheap enough to pick it."""
     cfg = SpikformerConfig().scaled()
     params = init(jax.random.PRNGKey(0), cfg)
     ref = _compiled(params, cfg, backend="reference")
-    pal = _compiled(params, cfg, backend="packed", pallas=True, jit=False)
+    pal = _compiled(params, cfg, backend="packed", pallas=True, jit=False,
+                    route_constants=CHEAP_PALLAS_GATHER)
 
     def lut_layers(model):
         for path, route in model.plan.routes.items():

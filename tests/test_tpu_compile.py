@@ -9,11 +9,13 @@ the TPU library), and the persistent compilation cache is off around these
 compiles (an entry compiled for a described chip cannot be read back here).
 
 The file also pins the single interpret decision
-(``kernels.device.resolve_interpret``) and the planner rule that keeps the
-fused MLP kernel off the published widths.
+(``kernels.device.resolve_interpret``) and the planner rules at the
+published widths: the fused MLP kernel never runs, and the v5e-fitted
+Pallas cost model sends every linear layer to the unpack-dot.
 """
 from __future__ import annotations
 
+import functools
 import os
 
 import jax
@@ -26,7 +28,7 @@ from repro.core.spikformer import fold_inference_params, init
 from repro.infer import get_backend, plan_route_tables, quantize_folded
 from repro.kernels import device, fused
 from repro.kernels.fused import tflif_lut_matmul
-from repro.kernels.lut_matmul import table_bytes
+from repro.kernels.lut_matmul import RouteConstants, table_bytes
 from repro.kernels.spike_matmul import lut_gather_matmul, spike_matmul
 from repro.kernels.stdp_attention import stdp_attention
 from repro.kernels.tflif import tflif_fused
@@ -143,20 +145,63 @@ def test_fused_mlp_compiles_at_its_largest_table(one_chip):
         ((k // 8, 256, n), jnp.int16), ((k,), jnp.float32))
 
 
-@pytest.mark.parametrize("cfg", [CONFIG, CONFIG_T16], ids=["T4", "T16"])
-def test_published_widths_never_route_the_fused_mlp(cfg):
+PUBLISHED = {"T4": CONFIG, "T16": CONFIG_T16}
+
+
+@functools.cache
+def _published_folded(name):
+    """Shapes of the int8 folded tree of a published configuration."""
+    cfg = PUBLISHED[name]
+    return jax.eval_shape(lambda: quantize_folded(
+        fold_inference_params(init(jax.random.PRNGKey(0), cfg), cfg)))
+
+
+@pytest.mark.parametrize("name", PUBLISHED)
+def test_published_widths_never_route_the_fused_mlp(name):
     """At the published widths fc2's byte-LUT table is past the planner's
     cap, so it gets no table, and the fused MLP kernel (which needs one,
     and keeps it VMEM-resident) never runs."""
-    folded = jax.eval_shape(lambda: quantize_folded(
-        fold_inference_params(init(jax.random.PRNGKey(0), cfg), cfg)))
-    tree, routes = plan_route_tables(folded, cfg, batch_size=8,
-                                     build_tables=False, pallas=True)
+    cfg = PUBLISHED[name]
+    tree, routes = plan_route_tables(_published_folded(name), cfg,
+                                     batch_size=8, build_tables=False,
+                                     pallas=True)
     for i in range(cfg.depth):
         fc2 = tree["blocks"][f"b{i}"]["mlp"]["fc2"]
         assert routes[f"blocks/b{i}/mlp/fc2"] == "unpack"
         assert "lut" not in fc2
     assert table_bytes(HIDDEN, DIM, True) > fused.MAX_TABLE_BYTES
+
+
+@pytest.mark.parametrize("batch_size", [1, 2, 4, 8, 32])
+@pytest.mark.parametrize("name", PUBLISHED)
+def test_published_widths_route_every_layer_to_the_dot(name, batch_size):
+    """Under the v5e-fitted Pallas constants the one-hot gather costs far
+    more than the 8 K-rows of dot it replaces, so at every bucket the
+    benchmark serves, every linear layer of both published configurations
+    takes the unpack-dot and no table is built."""
+    cfg = PUBLISHED[name]
+    tree, routes = plan_route_tables(_published_folded(name), cfg,
+                                     batch_size=batch_size,
+                                     build_tables=False, pallas=True)
+    assert routes and set(routes.values()) == {"unpack"}
+    leaves = jax.tree_util.tree_flatten_with_path(tree)[0]
+    assert not [p for p, _ in leaves if "'lut'" in jax.tree_util.keystr(p)]
+
+
+@pytest.mark.parametrize("name", PUBLISHED)
+def test_cheap_gather_constants_still_route_attention_to_the_lut(name):
+    """The cost model still decides: priced at the pre-fit 2 FMAs a
+    selected element, the int8 q/k/v/wo layers (16 MiB tables, at the cap)
+    go back to the gather, while fc2 (past the cap) stays on the dot."""
+    cfg = PUBLISHED[name]
+    cheap = RouteConstants(pallas_gather_cost=2.0, pallas_dot_cost=1.0)
+    _, routes = plan_route_tables(_published_folded(name), cfg,
+                                  batch_size=8, build_tables=False,
+                                  constants=cheap, pallas=True)
+    for i in range(cfg.depth):
+        for w in ("wq", "wk", "wv", "wo"):
+            assert routes[f"blocks/b{i}/ssa/{w}"] == "lut"
+        assert routes[f"blocks/b{i}/mlp/fc2"] == "unpack"
 
 
 def test_interpret_is_decided_once(monkeypatch):
