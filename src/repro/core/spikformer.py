@@ -24,7 +24,7 @@ from ..nn.module import KeyStream, param_count
 from ..nn.layers import linear_init, linear
 from .lif import bn_init, bn_train_apply, bn_apply, tflif, fold_bn
 from .spike import rate_decode
-from .unified import sssc, zsc, wssl
+from .unified import LayerSpec, sssc, zsc, wssl
 from .ssa import ssa_init, ssa_apply
 
 
@@ -204,20 +204,114 @@ def fold_inference_params(params, cfg: SpikformerConfig):
     return out
 
 
-def layer_paths(cfg: SpikformerConfig) -> list:
+def layer_specs(cfg: SpikformerConfig) -> list:
     """Every layer of one ``forward_folded`` pass, in call order: the stem's
     convs, then each block's q/k/v projections, its STDP attention, the
-    output projection and the two MLP layers. The one list of layer paths:
+    output projection and the two MLP layers. The one layer list:
     ``forward_folded`` selects each layer's weights and names its
-    ``jax.named_scope`` by it, and ``infer.compile.linear_layer_paths``
-    (route planning, occupancy calibration) is this list without the
-    attention."""
-    paths = [f"scs/conv{i}" for i in range(len(cfg.scs_channels))]
+    ``jax.named_scope`` by its path, and the compile passes
+    (``repro.infer.compile``) take each matmul's rows, K and N from it."""
+    side, cin = cfg.img_size, cfg.in_channels
+    specs = []
+    for i, cout in enumerate(cfg.scs_channels):
+        side //= 2
+        specs.append(LayerSpec(f"scs/conv{i}", "sssc" if i == 0 else "zsc",
+                               side * side, 4 * cin, cout))
+        cin = cout
+    n, d, hidden = cfg.tokens, cfg.dim, cfg.dim * cfg.mlp_ratio
     for i in range(cfg.depth):
-        paths += [f"blocks/b{i}/ssa/{op}"
-                  for op in ("wq", "wk", "wv", "stdp", "wo")]
-        paths += [f"blocks/b{i}/mlp/fc1", f"blocks/b{i}/mlp/fc2"]
-    return paths
+        specs += block_specs(f"blocks/b{i}", n, d, hidden)
+    return specs
+
+
+def block_specs(prefix: str, n: int, d: int, hidden: int) -> list:
+    """The layers of one encoder block (``encoder_block``) at ``prefix``
+    over ``n`` tokens of width ``d``."""
+    return ([LayerSpec(f"{prefix}/ssa/{w}", "wssl", n, d, d)
+             for w in ("wq", "wk", "wv")]
+            + [LayerSpec(f"{prefix}/ssa/stdp", "stdp", n),
+               LayerSpec(f"{prefix}/ssa/wo", "wssl", n, d, d),
+               LayerSpec(f"{prefix}/mlp/fc1", "wssl", n, d, hidden),
+               LayerSpec(f"{prefix}/mlp/fc2", "wssl", n, hidden, d)])
+
+
+def layer_paths(cfg: SpikformerConfig) -> list:
+    """The paths of ``layer_specs``, in call order."""
+    return [s.path for s in layer_specs(cfg)]
+
+
+def tree_at(tree, path: str):
+    """The node of a nested dict at a ``/``-separated layer path."""
+    for key in path.split("/"):
+        tree = tree[key]
+    return tree
+
+
+class LayerRunner:
+    """Runs the layers of a folded tree through an execution backend: each
+    by its path, under a ``jax.named_scope`` of that path.
+
+    ``layer_occupancy`` maps layer paths to STATIC calibrated
+    chunk-occupancy floats for layers the plan routed "lut_sparse"; the
+    kwarg is forwarded to a backend method only for layers that carry a
+    value, so backends without the ``occupancy`` parameter keep working
+    under dense plans."""
+
+    def __init__(self, folded, backend, t: int, layer_occupancy=None):
+        self.folded, self.backend, self.t = folded, backend, t
+        self.occ = layer_occupancy or {}
+
+    def extra(self, path):
+        o = self.occ.get(path)
+        return {} if o is None else {"occupancy": o}
+
+    def at(self, path):
+        return tree_at(self.folded, path)
+
+    def linear(self, op, z, path, **kw):
+        layer = self.at(path)
+        with jax.named_scope(path):
+            return op(z, layer["kernel"], layer["bias"], t=self.t,
+                      scale=layer.get("scale"), lut=layer.get("lut"),
+                      **self.extra(path), **kw)
+
+    def head(self, x):
+        with jax.named_scope("head"):
+            rate = self.backend.rate(x, t=self.t)        # (B, D)
+            head = self.folded["head"]
+            logits = rate @ head["kernel"].astype(rate.dtype)
+            if "bias" in head:
+                logits = logits + head["bias"].astype(logits.dtype)
+        return logits
+
+
+def encoder_block(run: LayerRunner, x, prefix: str, *, heads: int,
+                  scale: float, residual: str):
+    """One Spikformer encoder block at ``prefix`` (``blocks/b3``): q/k/v
+    projections, STDP attention, the output projection and the MLP, each
+    combined with the block input by ``residual``."""
+    backend, t = run.backend, run.t
+    q, k, v = (run.linear(backend.wssl_lif, x, f"{prefix}/ssa/{w}")
+               for w in ("wq", "wk", "wv"))
+    with jax.named_scope(f"{prefix}/ssa/stdp"):
+        att = backend.stdp_lif(q, k, v, heads=heads, scale=scale, t=t)
+    att = run.linear(backend.wssl_lif, att, f"{prefix}/ssa/wo")
+    x = backend.residual(att, x, residual)
+    fc1, fc2 = f"{prefix}/mlp/fc1", f"{prefix}/mlp/fc2"
+    # backends exposing ``mlp_pair_lif`` may fuse the fc1 -> LIF -> fc2
+    # step into one kernel (packed spikes never unpacked in HBM); a None
+    # return means "not applicable here" and the two-layer composition
+    # below is the universal fallback — both are bit-exact against each
+    # other, so the choice never changes logits
+    s2 = None
+    pair = getattr(backend, "mlp_pair_lif", None)
+    if pair is not None:
+        with jax.named_scope(f"{prefix}/mlp"):
+            s2 = pair(x, run.at(fc1), run.at(fc2), t=t, **run.extra(fc1))
+    if s2 is None:
+        s2 = run.linear(backend.wssl_lif,
+                        run.linear(backend.wssl_lif, x, fc1), fc2)
+    return backend.residual(s2, x, residual)
 
 
 def forward_folded(folded, images_u8, cfg: SpikformerConfig, *, backend,
@@ -240,76 +334,25 @@ def forward_folded(folded, images_u8, cfg: SpikformerConfig, *, backend,
 
     ``layer_occupancy`` maps layer paths ("scs/conv0", "blocks/b0/ssa/wq",
     ...) to STATIC calibrated chunk-occupancy floats for layers the plan
-    routed "lut_sparse". It is closed over, never part of the traced tree
-    — the sparse gather budget must be a compile-time constant. The kwarg
-    is forwarded to a backend method only for layers that carry a value,
-    so backends without the ``occupancy`` parameter keep working under
-    dense plans. Returns (B, num_classes) logits.
+    routed "lut_sparse" (``LayerRunner``). It is closed over, never part of
+    the traced tree — the sparse gather budget must be a compile-time
+    constant. Returns (B, num_classes) logits.
 
     Each layer runs under a ``jax.named_scope`` of its path (the fused MLP
     pair under ``blocks/b{i}/mlp``), the token reshape under ``tokens`` and
     the readout under ``head``, so the compiled program's op metadata says
     which layer each device op belongs to.
     """
-    t = cfg.timesteps
-    occ = layer_occupancy or {}
-    paths = iter(layer_paths(cfg))      # call order: each call takes its own
-
-    def extra(path):
-        o = occ.get(path)
-        return {} if o is None else {"occupancy": o}
-
-    def at(path):
-        node = folded
-        for key in path.split("/"):
-            node = node[key]
-        return node
-
-    def linear(op, z, path):
-        layer = at(path)
-        with jax.named_scope(path):
-            return op(z, layer["kernel"], layer["bias"], t=t,
-                      scale=layer.get("scale"), lut=layer.get("lut"),
-                      **extra(path))
-
-    x = linear(backend.sssc_lif, images_u8, next(paths))
-    for _ in cfg.scs_channels[1:]:
-        x = linear(backend.zsc_lif, x, next(paths))
+    run = LayerRunner(folded, backend, cfg.timesteps, layer_occupancy)
+    x = run.linear(backend.sssc_lif, images_u8, "scs/conv0")
+    for i in range(1, len(cfg.scs_channels)):
+        x = run.linear(backend.zsc_lif, x, f"scs/conv{i}")
     with jax.named_scope("tokens"):
         x = backend.to_tokens(x)
-
-    for _ in range(cfg.depth):
-        wq, wk, wv, stdp, wo, fc1, fc2 = (next(paths) for _ in range(7))
-        q = linear(backend.wssl_lif, x, wq)
-        k = linear(backend.wssl_lif, x, wk)
-        v = linear(backend.wssl_lif, x, wv)
-        with jax.named_scope(stdp):
-            att = backend.stdp_lif(q, k, v, heads=cfg.heads,
-                                   scale=cfg.attn_scale, t=t)
-        att = linear(backend.wssl_lif, att, wo)
-        x = backend.residual(att, x, cfg.residual)
-        # backends exposing ``mlp_pair_lif`` may fuse the fc1 -> LIF -> fc2
-        # step into one kernel (packed spikes never unpacked in HBM); a
-        # None return means "not applicable here" and the two-layer
-        # composition below is the universal fallback — both are bit-exact
-        # against each other, so the choice never changes logits
-        s2 = None
-        pair = getattr(backend, "mlp_pair_lif", None)
-        if pair is not None:
-            with jax.named_scope(fc1.rsplit("/", 1)[0]):
-                s2 = pair(x, at(fc1), at(fc2), t=t, **extra(fc1))
-        if s2 is None:
-            s2 = linear(backend.wssl_lif, linear(backend.wssl_lif, x, fc1),
-                        fc2)
-        x = backend.residual(s2, x, cfg.residual)
-
-    with jax.named_scope("head"):
-        rate = backend.rate(x, t=t)                     # (B, D)
-        head = folded["head"]
-        logits = rate @ head["kernel"].astype(rate.dtype)
-        if "bias" in head:
-            logits = logits + head["bias"].astype(logits.dtype)
-    return logits
+    for i in range(cfg.depth):
+        x = encoder_block(run, x, f"blocks/b{i}", heads=cfg.heads,
+                          scale=cfg.attn_scale, residual=cfg.residual)
+    return run.head(x)
 
 
 def loss_fn(params, batch, cfg: SpikformerConfig, *, train: bool = True):

@@ -9,6 +9,11 @@ primitive — a weight-stationary matmul over binary planes — differing only i
   SSSC  planes = 8 bit-planes of a uint8, outputs summed with scales 2^k
   STDP  planes = T timesteps,             (Q Kt) V fused tile-wise, no softmax
 
+QKFormer (``core.qkformer``) adds layout and attention ops around the same
+primitive: ``im2col`` (a 3x3 conv becomes WSSL/SSSC rows), ``max_pool``
+over ``pool_windows`` and Q-K token attention (``qkta``). ``LayerSpec`` is
+the entry of a network's layer list, which the compile passes read.
+
 This module is the float/differentiable reference used for training (spikes
 are {0,1} floats carrying surrogate gradients). The packed-bit inference path
 lives in ``repro.kernels`` (Pallas, `spike_matmul` / `stdp_attention`), with
@@ -16,10 +21,41 @@ lives in ``repro.kernels`` (Pallas, `spike_matmul` / `stdp_attention`), with
 """
 from __future__ import annotations
 
+import dataclasses
+import functools
+
 import jax
 import jax.numpy as jnp
 
+from .lif import tflif
 from .spike import space_to_depth, bitplanes_u8
+
+# the dataflows that are a spiking matmul (the others have no weights)
+MATMUL_OPS = ("sssc", "zsc", "conv", "wssl")
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerSpec:
+    """One layer of a network's inference forward, as the model's layer
+    list gives it, in call order.
+
+    ``path`` is the layer's key path in the folded tree and the name of its
+    ``jax.named_scope``. ``op`` is its dataflow: "sssc" (a matmul over the
+    8 value planes of uint8 pixels), "zsc" (2x2/s2 spike conv), "conv"
+    (k x k stride-1 spike conv), "wssl" (spike linear), or a weightless
+    "stdp" / "qkta" attention. A matmul layer computes ``rows`` output
+    positions an image, each a ``k``-long contraction into ``n`` outputs;
+    the compile passes plan and check its weights from these numbers.
+    """
+    path: str
+    op: str
+    rows: int = 0
+    k: int = 0
+    n: int = 0
+
+    @property
+    def matmul(self) -> bool:
+        return self.op in MATMUL_OPS
 
 
 def wssl(spikes, kernel, bias=None, *, compute_dtype=jnp.float32):
@@ -64,7 +100,13 @@ def sssc(image_u8, kernel, bias=None, *, compute_dtype=jnp.float32):
     all T (paper Sec. II-D).
     """
     x = space_to_depth(image_u8, 2)                     # (B,H/2,W/2,4C) uint8
-    planes = bitplanes_u8(x, dtype=compute_dtype)       # (8, B, H/2, W/2, 4C)
+    return shift_sum_linear(x, kernel, bias, compute_dtype=compute_dtype)
+
+
+def shift_sum_linear(x_u8, kernel, bias=None, *, compute_dtype=jnp.float32):
+    """SSSC's matmul over uint8 values already laid out as rows: (..., K)
+    uint8, kernel (K, F) -> (..., F), ``sum_k 2^k (plane_k . W)``."""
+    planes = bitplanes_u8(x_u8, dtype=compute_dtype)    # (8, ..., K)
     k = kernel.reshape((-1, kernel.shape[-1]))
     per_plane = wssl(planes, k, None, compute_dtype=compute_dtype)  # (8,...,F)
     scales = (2.0 ** jnp.arange(8, dtype=compute_dtype)).reshape(
@@ -73,6 +115,46 @@ def sssc(image_u8, kernel, bias=None, *, compute_dtype=jnp.float32):
     if bias is not None:
         y = y + bias.astype(y.dtype)
     return y
+
+
+def im2col(x):
+    """The patches of a 3x3, stride-1 convolution zero-padded to keep its
+    size: (..., H, W, C) -> (..., H, W, 9C), ordered (dy, dx, c) as a
+    (3, 3, C, F) kernel reshaped to (9C, F). Pure layout, so the same call
+    serves float spike trains, packed plane groups (a zero byte is a silent
+    neuron at every timestep) and uint8 images (a zero pixel)."""
+    h, w = x.shape[-3], x.shape[-2]
+    xp = jnp.pad(x, [(0, 0)] * (x.ndim - 3) + [(1, 1), (1, 1), (0, 0)])
+    return jnp.concatenate([xp[..., dy:dy + h, dx:dx + w, :]
+                            for dy in range(3) for dx in range(3)], axis=-1)
+
+
+def pool_windows(x):
+    """The 9 strided views of a 3/2/1 pooling window over (..., H, W, C),
+    zero-padded: each (..., ceil(H/2), ceil(W/2), C). A zero pad never
+    wins a max of binary values, since every window holds a real position."""
+    ho, wo = -(-x.shape[-3] // 2), -(-x.shape[-2] // 2)
+    xp = jnp.pad(x, [(0, 0)] * (x.ndim - 3) + [(1, 1), (1, 1), (0, 0)])
+    return [xp[..., dy:dy + 2 * ho - 1:2, dx:dx + 2 * wo - 1:2, :]
+            for dy in range(3) for dx in range(3)]
+
+
+def max_pool(spikes):
+    """Spiking 3/2/1 max-pool over (T, B, H, W, C) float spike trains."""
+    return functools.reduce(jnp.maximum, pool_windows(spikes))
+
+
+def qkta(q, k, *, heads: int, v_th: float):
+    """Q-K token attention (QKFormer's QKTA) over float spike trains
+    (T, B, N, D): per head h, a LIF with threshold ``v_th`` over the count
+    of Q's spikes in the head's channels gives the token mask
+    A[t, b, n, h]; the output keeps K where its head's mask fired,
+    X'[t, b, n, c] = A[t, b, n, h(c)] * K[t, b, n, c]. No V, and linear in
+    the token count."""
+    *lead, d = q.shape
+    counts = q.reshape(*lead, heads, d // heads).sum(axis=-1)
+    a = tflif(counts, v_th=v_th)                        # (T, B, N, H)
+    return jnp.repeat(a, d // heads, axis=-1) * k
 
 
 def stdp(q, k, v, *, scale: float, compute_dtype=jnp.float32):

@@ -45,12 +45,15 @@ weights relaxes to reduction-order tolerance (pin "lut" routes there).
 """
 from __future__ import annotations
 
+import functools
+import math
+
 import jax.numpy as jnp
 from jax import lax
 
 from . import registry
 from ..core import unified
-from ..core.lif import V_TH, tflif
+from ..core.lif import TAU, V_TH, tflif
 from ..core.spike import (bitplanes_u8, packed_occupancy, rate_decode,
                           space_to_depth)
 from ..kernels import fused, ops
@@ -87,6 +90,13 @@ def value_chunk_occupancy(x_u8) -> float:
     """Chunk occupancy of uint8 *value* bytes (the SSSC operand): the
     8 bit-planes of the values are the LUT index source directly."""
     return chunk_occupancy(x_u8[None], 8)
+
+
+def _to_spatial(x):
+    """(lead, lead, N, D) tokens -> (lead, lead, side, side, D), N = side^2."""
+    *lead, n, d = x.shape
+    side = math.isqrt(n)
+    return x.reshape(*lead, side, side, d)
 
 
 class FloatBackend:
@@ -127,9 +137,12 @@ class FloatBackend:
         return cls._wssl_emu(space_to_depth(spikes, 2),
                              kernel.reshape(-1, kernel.shape[-1]), bias)
 
+    @classmethod
+    def _sssc_emu(cls, image_u8, kernel, bias=None):
+        return cls._value_emu(space_to_depth(image_u8, 2), kernel, bias)
+
     @staticmethod
-    def _sssc_emu(image_u8, kernel, bias=None):
-        x = space_to_depth(image_u8, 2)                 # (B, h, w, 4C) u8
+    def _value_emu(x, kernel, bias=None):
         lead = x.shape[:-1]
         planes = bitplanes_u8(x).reshape(8, -1, x.shape[-1])
         per = lut.lut_matmul_planes(planes, kernel)     # (8, M, N)
@@ -143,13 +156,25 @@ class FloatBackend:
     # from the fold, so its bit-exact float emulation is the same
     # ``lut_matmul_planes`` replay the dense LUT route already uses.
 
-    def sssc_lif(self, images_u8, kernel, bias, *, t: int, scale=None,
-                 lut=None, occupancy=None):
-        op = unified.sssc if lut is None else self._sssc_emu
-        y, vth = self._acc_and_vth(op, images_u8, kernel, bias,
-                                   scale)                # (B, H/2, W/2, F)
+    def value_lif(self, x_u8, kernel, bias, *, t: int, scale=None,
+                  lut=None, occupancy=None):
+        """SSSC over uint8 values already laid out as rows (..., K)."""
+        op = unified.shift_sum_linear if lut is None else self._value_emu
+        y, vth = self._acc_and_vth(op, x_u8, kernel, bias, scale)
         y = jnp.broadcast_to(y[None], (t, *y.shape))    # image constant in T
         return tflif(y, v_th=vth)
+
+    def sssc_lif(self, images_u8, kernel, bias, **kw):
+        return self.value_lif(space_to_depth(images_u8, 2), kernel, bias,
+                              **kw)
+
+    def image_conv_lif(self, images_u8, kernel, bias, **kw):
+        """3x3 stride-1 conv on the 8-bit image (SSSC, once for all T)."""
+        return self.value_lif(unified.im2col(images_u8), kernel, bias, **kw)
+
+    def conv_lif(self, x, kernel, bias, **kw):
+        """3x3 stride-1 zero-padded conv over spikes."""
+        return self.wssl_lif(unified.im2col(x), kernel, bias, **kw)
 
     def zsc_lif(self, x, kernel, bias, *, t: int, scale=None, lut=None,
                 occupancy=None):
@@ -174,6 +199,13 @@ class FloatBackend:
         att = tflif(att)                                # (T, B, H, N, dh)
         return att.transpose(0, 1, 3, 2, 4).reshape(tt, b, n, d)
 
+    def qkta(self, q, k, *, heads: int, t: int, v_th: float):
+        """Q-K token attention with the LIF run literally over T."""
+        return unified.qkta(q, k, heads=heads, v_th=v_th)
+
+    def max_pool(self, x):
+        return unified.max_pool(x)
+
     def residual(self, new, res, mode: str):
         if mode == "iand":
             return (1.0 - new) * res
@@ -182,6 +214,9 @@ class FloatBackend:
     def to_tokens(self, x):
         tt, b, h, w, c = x.shape
         return x.reshape(tt, b, h * w, c)
+
+    def to_spatial(self, x):
+        return _to_spatial(x)
 
     def rate(self, x, *, t: int):
         return rate_decode(x, axis=0).mean(axis=1)      # (B, D)
@@ -230,14 +265,28 @@ class PackedBackend:
     # calibration (present only for "lut_sparse"-routed layers); the ops
     # layer derives the zero-chunk-skipping gather budget from it.
 
-    def sssc_lif(self, images_u8, kernel, bias, *, t: int, scale=None,
-                 lut=None, occupancy=None):
-        x = space_to_depth(images_u8, 2)                # (B,H/2,W/2,4C) u8
-        acc = ops.sssc_linear(x, self._w(kernel, scale), None,
+    def value_lif(self, x_u8, kernel, bias, *, t: int, scale=None,
+                  lut=None, occupancy=None):
+        """SSSC over uint8 values already laid out as rows (..., K)."""
+        acc = ops.sssc_linear(x_u8, self._w(kernel, scale), None,
                               pallas=self.pallas, table=lut,
                               occupancy=occupancy)
         acc = jnp.broadcast_to(acc[None], (t, *acc.shape))
-        return self._lif(acc, bias, scale)              # (G,B,H/2,W/2,F) u8
+        return self._lif(acc, bias, scale)              # (G, ..., F) u8
+
+    def sssc_lif(self, images_u8, kernel, bias, **kw):
+        return self.value_lif(space_to_depth(images_u8, 2), kernel, bias,
+                              **kw)
+
+    def image_conv_lif(self, images_u8, kernel, bias, **kw):
+        """3x3 stride-1 conv on the 8-bit image: im2col of the pixel bytes,
+        then SSSC (once for all T)."""
+        return self.value_lif(unified.im2col(images_u8), kernel, bias, **kw)
+
+    def conv_lif(self, x, kernel, bias, **kw):
+        """3x3 stride-1 zero-padded conv over spikes: im2col of the packed
+        bytes, then the spike linear."""
+        return self.wssl_lif(unified.im2col(x), kernel, bias, **kw)
 
     def zsc_lif(self, x, kernel, bias, *, t: int, scale=None, lut=None,
                 occupancy=None):
@@ -303,6 +352,26 @@ class PackedBackend:
         att = ops.tflif_pack(acc, pallas=self.pallas)   # (G, B, H, N, dh) u8
         return att.transpose(0, 1, 3, 2, 4).reshape(g, b, n, d)
 
+    def qkta(self, q, k, *, heads: int, t: int, v_th: float):
+        """Q-K token attention on packed bytes. With tau = 2 and a hard
+        reset, the mask neuron's input is a whole count, and for
+        0 < v_th <= 1/tau it fires exactly when that count is at least 1:
+        the mask is the OR of the head's Q bytes, which carry every
+        timestep at once (``ops.qk_token_attention``). For any other
+        threshold the LIF keeps state across timesteps and that form
+        would be wrong, so it is refused."""
+        if not 0.0 < v_th <= 1.0 / TAU:
+            raise ValueError(
+                f"packed QKTA computes the mask as an OR, exact only for "
+                f"0 < v_th <= 1/tau = {1.0 / TAU}; got v_th={v_th}")
+        return ops.qk_token_attention(q, k, heads=heads, t=t,
+                                      pallas=self.pallas)
+
+    def max_pool(self, x):
+        """Spiking 3/2/1 max-pool: the max of binary values is their OR,
+        which on packed bytes pools every timestep at once."""
+        return functools.reduce(jnp.bitwise_or, unified.pool_windows(x))
+
     def residual(self, new, res, mode: str):
         if mode != "iand":
             raise ValueError(
@@ -316,6 +385,9 @@ class PackedBackend:
     def to_tokens(self, x):
         g, b, h, w, c = x.shape
         return x.reshape(g, b, h * w, c)
+
+    def to_spatial(self, x):
+        return _to_spatial(x)
 
     def rate(self, x, *, t: int):
         # popcount readout: sum of bits per neuron without unpacking. The
@@ -343,11 +415,11 @@ class OccupancyRecorder(PackedBackend):
         super().__init__(pallas=False)
         self.trace: list[float] = []
 
-    def sssc_lif(self, images_u8, kernel, bias, *, t: int, scale=None,
-                 lut=None, occupancy=None):
-        self.trace.append(value_chunk_occupancy(space_to_depth(images_u8, 2)))
-        return super().sssc_lif(images_u8, kernel, bias, t=t, scale=scale,
-                                lut=lut)
+    def value_lif(self, x_u8, kernel, bias, *, t: int, scale=None,
+                  lut=None, occupancy=None):
+        self.trace.append(value_chunk_occupancy(x_u8))
+        return super().value_lif(x_u8, kernel, bias, t=t, scale=scale,
+                                 lut=lut)
 
     def zsc_lif(self, x, kernel, bias, *, t: int, scale=None, lut=None,
                 occupancy=None):

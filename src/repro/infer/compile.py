@@ -36,14 +36,36 @@ import jax.numpy as jnp
 
 from . import backends as _backends  # noqa: F401  (registers built-ins)
 from . import registry
-from .quant import WEIGHT_DTYPES, map_folded_layers, quantize_folded
-from ..core import spikformer
-from ..core.spikformer import SpikformerConfig, fold_inference_params
+from .quant import (WEIGHT_DTYPES, folded_layers, map_folded_layers,
+                    quantize_folded)
+from ..core import qkformer, spikformer
+from ..core.qkformer import QKFormerConfig
+from ..core.spikformer import SpikformerConfig
 from ..kernels import lut_matmul
 from ..kernels.lut_matmul import RouteConstants
 from ..kernels.ops import choose_pallas_route, choose_route, use_pallas
 
 ROUTES = ("auto", "unpack", "lut")
+
+# each network's module: ``layer_specs``, ``fold_inference_params`` and
+# ``forward_folded`` for its config type
+_NETWORKS = {SpikformerConfig: spikformer, QKFormerConfig: qkformer}
+
+
+def network(cfg):
+    """The module that describes ``cfg``'s network."""
+    try:
+        return _NETWORKS[type(cfg)]
+    except KeyError:
+        raise TypeError(f"no network is registered for {type(cfg).__name__}; "
+                        f"expected one of "
+                        f"{sorted(c.__name__ for c in _NETWORKS)}") from None
+
+
+def matmul_specs(cfg) -> dict:
+    """path -> ``LayerSpec`` of every spiking matmul of ``cfg``'s network,
+    in call order."""
+    return {s.path: s for s in network(cfg).layer_specs(cfg) if s.matmul}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -148,12 +170,13 @@ class ExecutionPlan:
 # tests and the autotuner can run them in isolation.
 # ---------------------------------------------------------------------------
 
-def fold_bn(params, cfg: SpikformerConfig, *, folded: bool = False):
+def fold_bn(params, cfg, *, folded: bool = False):
     """Pass 1 — BN folding: training params -> inference tree of
-    {kernel, bias} layers (``core.spikformer.fold_inference_params``).
+    {kernel, bias} layers (the network's ``fold_inference_params``).
     ``folded=True`` passes a pre-folded (possibly pre-quantized) tree
     through untouched."""
-    return params if folded else fold_inference_params(params, cfg)
+    return params if folded else network(cfg).fold_inference_params(
+        params, cfg)
 
 
 def quantize_weights(tree, weight_dtype: str | None):
@@ -166,7 +189,8 @@ def quantize_weights(tree, weight_dtype: str | None):
     if weight_dtype is not None and weight_dtype not in WEIGHT_DTYPES:
         raise ValueError(f"unknown weight_dtype {weight_dtype!r}; "
                          f"expected one of {WEIGHT_DTYPES}")
-    already_quantized = "scale" in tree["scs"]["conv0"]
+    already_quantized = any("scale" in layer
+                            for _, layer in folded_layers(tree))
     if weight_dtype == "float32" and already_quantized:
         raise ValueError(
             "weight_dtype='float32' requested but the folded tree is "
@@ -179,7 +203,7 @@ def quantize_weights(tree, weight_dtype: str | None):
     return tree, resolved
 
 
-def plan_route_tables(folded, cfg: SpikformerConfig, *, batch_size: int,
+def plan_route_tables(folded, cfg, *, batch_size: int,
                       max_table_bytes: int = lut_matmul.MAX_TABLE_BYTES,
                       build_tables: bool = True,
                       constants: RouteConstants | None = None,
@@ -189,10 +213,12 @@ def plan_route_tables(folded, cfg: SpikformerConfig, *, batch_size: int,
                       pallas: bool = False):
     """Pass 3 — per-layer matmul route planning: the byte-LUT's precompute.
 
-    For every folded layer this computes the packed-route matmul shape
-    (M, K, N, G) the compiled step will see at ``batch_size`` and decides
-    between the unpack-free byte-LUT datapath and the unpack-then-dot
-    oracle — via ``kernels.ops.choose_route`` under ``constants`` when
+    For every folded layer this takes the packed-route matmul shape
+    (M, K, N, G) the compiled step will see at ``batch_size`` from the
+    network's layer list (``matmul_specs``: M is ``batch_size`` times the
+    layer's rows an image; a layer whose kernel is not (K, N) is refused)
+    and decides between the unpack-free byte-LUT datapath and the
+    unpack-then-dot oracle — via ``kernels.ops.choose_route`` under ``constants`` when
     ``routes`` is None, or by REPLAYING a pinned ``routes`` mapping (what a
     deserialized plan carries). Where the LUT wins, the (C, 256, N)
     chunk-partial-sum table is built ONCE and cached in the returned tree
@@ -228,25 +254,23 @@ def plan_route_tables(folded, cfg: SpikformerConfig, *, batch_size: int,
     """
     t = cfg.timesteps
     g = -(-t // 8)
-    m_tok = batch_size * cfg.tokens
+    specs = matmul_specs(cfg)
     plan = {}
     occ_map = layer_occupancy or {}
     choose = choose_pallas_route if pallas else choose_route
 
-    def shapes_for(path):
-        """Packed-route matmul shape (m, live planes, groups) at ``path``."""
-        if path.startswith("scs/conv"):
-            i = int(path.removeprefix("scs/conv"))
-            m = batch_size * (cfg.img_size // 2 ** (i + 1)) ** 2
-            # conv0 is SSSC: always 8 value planes, one group
-            return (m, 8, 1) if i == 0 else (m, t, g)
-        return m_tok, t, g
-
     def annotate(path, layer):
         wq = layer["kernel"]
+        spec = specs.get(path)
+        if spec is None or tuple(wq.shape) != (spec.k, spec.n):
+            raise ValueError(
+                f"folded layer {path!r} with kernel {tuple(wq.shape)} is not "
+                f"a matmul of the layer list of {type(cfg).__name__} "
+                f"({None if spec is None else (spec.k, spec.n)})")
         if routes is None:
-            m, tt, gg = shapes_for(path)
-            k, n = wq.shape
+            m, k, n = batch_size * spec.rows, spec.k, spec.n
+            # SSSC: always 8 value planes, one group
+            tt, gg = (8, 1) if spec.op == "sssc" else (t, g)
             is_int = jnp.issubdtype(wq.dtype, jnp.integer)
             route = force or choose(m=m, k=k, n=n, g=gg, t=tt,
                                     weights_are_int=is_int,
@@ -288,17 +312,16 @@ def strip_lut_annotations(folded):
         folded, lambda _, l: {k: v for k, v in l.items() if k != "lut"})
 
 
-def linear_layer_paths(cfg: SpikformerConfig) -> list:
+def linear_layer_paths(cfg) -> list:
     """Layer paths in FORWARD-CALL order — the order a single
     ``forward_folded`` pass hits each spiking linear, which is the order
     ``backends.OccupancyRecorder`` appends its trace in. (``map_folded_layers``
     walks the same paths but in tree order; calibration needs call order.)
-    ``spikformer.layer_paths`` without the STDP attention."""
-    return [p for p in spikformer.layer_paths(cfg)
-            if not p.endswith("/stdp")]
+    The network's layer list without the weightless attention layers."""
+    return list(matmul_specs(cfg))
 
 
-def calibrate_layer_occupancy(params, cfg: SpikformerConfig, images_u8, *,
+def calibrate_layer_occupancy(params, cfg, images_u8, *,
                               folded: bool = False,
                               weight_dtype: str | None = None) -> dict:
     """Measure per-layer chunk occupancy on a calibration batch.
@@ -328,7 +351,7 @@ def calibrate_layer_occupancy(params, cfg: SpikformerConfig, images_u8, *,
     return dict(zip(paths, recorder.trace))
 
 
-def lower(folded, cfg: SpikformerConfig, backend, *, jit: bool = True,
+def lower(folded, cfg, backend, *, jit: bool = True,
           layer_occupancy: dict | None = None):
     """Pass 4 — lowering: the annotated tree becomes one step callable
     (jitted unless ``jit=False``; each batch bucket compiles its own
@@ -338,10 +361,11 @@ def lower(folded, cfg: SpikformerConfig, backend, *, jit: bool = True,
     "lut_sparse") is CLOSED OVER, not threaded through the traced tree —
     the sparse gather budget must be a trace-time constant, and the folded
     tree is a jit argument whose leaves become tracers."""
+    forward = network(cfg).forward_folded
+
     def fwd(folded_tree, images):
-        return spikformer.forward_folded(folded_tree, images, cfg,
-                                         backend=backend,
-                                         layer_occupancy=layer_occupancy)
+        return forward(folded_tree, images, cfg, backend=backend,
+                       layer_occupancy=layer_occupancy)
 
     return jax.jit(fwd) if jit else fwd
 
@@ -387,8 +411,9 @@ def plan_chunks(n: int, buckets) -> list:
 
 
 class CompiledModel:
-    """A Spikformer lowered under an ``ExecutionPlan``: one jit-compiled
-    fixed-shape step per batch bucket over an annotated folded tree.
+    """A network (Spikformer, QKFormer) lowered under an ``ExecutionPlan``:
+    one jit-compiled fixed-shape step per batch bucket over an annotated
+    folded tree.
 
     ``plan`` is the RESOLVED plan — ``weight_dtype`` concretized and the
     per-layer ``routes`` filled in — so ``model.plan.to_json()`` is the
@@ -479,11 +504,13 @@ class CompiledModel:
         return self.logits(images_u8)
 
 
-def compile(params, cfg: SpikformerConfig, plan: ExecutionPlan | None = None,
+def compile(params, cfg, plan: ExecutionPlan | None = None,
             *, folded: bool = False, jit: bool = True,
             **plan_overrides) -> CompiledModel:
     """Run the pass pipeline under ``plan`` and return a ``CompiledModel``.
 
+    ``cfg`` is a ``SpikformerConfig`` or a ``QKFormerConfig``: every pass
+    reads the network's shapes from its one layer list (``network``).
     ``params`` is a training tree (BN folded here) unless ``folded=True``,
     in which case it is already a ``fold_inference_params`` tree (possibly
     pre-quantized, possibly pre-annotated). ``plan_overrides`` are

@@ -35,21 +35,40 @@ import jax.numpy as jnp
 WEIGHT_DTYPES = ("float32", "int8")
 
 
+def folded_layers(folded):
+    """``(path, layer)`` of every conv/linear layer dict (one holding a
+    ``kernel``) of a folded tree, in tree order, below the top-level keys
+    other than the float ``head``."""
+    def walk(node, path):
+        if "kernel" in node:
+            yield path, node
+            return
+        for key, child in node.items():
+            if isinstance(child, dict):
+                yield from walk(child, f"{path}/{key}")
+
+    for key, node in folded.items():
+        if key != "head" and isinstance(node, dict):
+            yield from walk(node, key)
+
+
 def map_folded_layers(folded, fn):
     """Apply ``fn(path, layer) -> layer`` to every conv/linear layer dict of
-    a ``fold_inference_params`` tree, rebuilding the scs/blocks schema and
-    passing every other top-level key (head, ...) through untouched. The ONE
-    place the folded-tree layer schema is enumerated — quantization, route
-    planning, and annotation stripping all walk through here."""
-    out = dict(folded)
-    out["scs"] = {name: fn(f"scs/{name}", layer)
-                  for name, layer in folded["scs"].items()}
-    out["blocks"] = {
-        bname: {grp: {wn: fn(f"blocks/{bname}/{grp}/{wn}", layer)
-                      for wn, layer in sub.items()}
-                for grp, sub in blk.items()}
-        for bname, blk in folded["blocks"].items()}
-    return out
+    a folded tree (``folded_layers``), rebuilding the dicts above them and
+    passing everything else (the head, ...) through untouched. The ONE
+    place the folded-tree layer schema is walked — quantization, route
+    planning, and annotation stripping all go through here, for any
+    network."""
+    def walk(node, path):
+        if "kernel" in node:
+            return fn(path, node)
+        return {key: walk(child, f"{path}/{key}")
+                if isinstance(child, dict) else child
+                for key, child in node.items()}
+
+    return {key: walk(node, key)
+            if key != "head" and isinstance(node, dict) else node
+            for key, node in folded.items()}
 
 
 def quantize_layer(layer):
@@ -65,8 +84,8 @@ def quantize_layer(layer):
 def quantize_folded(folded):
     """Quantize a ``fold_inference_params`` tree to int8 weights.
 
-    Every SCS conv and every SSA/MLP linear gains a ``scale`` leaf and an
-    int8 ``kernel``; the float head is passed through unchanged. Backends
+    Every conv and linear gains a ``scale`` leaf and an int8 ``kernel``;
+    the float head is passed through unchanged. Backends
     detect the ``scale`` leaf and switch to the threshold-folded LIF.
     """
     return map_folded_layers(folded, lambda _, layer: quantize_layer(layer))

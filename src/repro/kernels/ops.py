@@ -27,6 +27,7 @@ from .spike_matmul import spike_matmul as _spike_matmul_pallas
 from .fused import tflif_lut_matmul as _tflif_lut_pallas
 from .tflif import tflif_fused as _tflif_pallas
 from .stdp_attention import stdp_attention as _stdp_pallas
+from .qk_token_attention import qk_token_attention as _qkta_pallas
 from .flash_attention import flash_attention as _flash_pallas
 from ..core.spike import bitplanes_u8, num_plane_groups, unpack_timesteps
 
@@ -495,3 +496,26 @@ def stdp_attention_packed(q_packed, k_packed, v_packed, *, t: int,
     out = stdp_attention(unfold(q_packed), unfold(k_packed), unfold(v_packed),
                          scale=scale, pallas=pallas, **blocks)
     return out.reshape((t, *lead, n, dh))
+
+
+def qk_token_attention(q_packed, k_packed, *, heads: int, t: int,
+                       pallas: bool | None = None):
+    """Packed Q-K token attention (QKFormer's QKTA in its OR form).
+
+    Args:
+      q_packed, k_packed: (G, ..., D) uint8 temporal plane groups of Q and
+        K; D splits into ``heads`` heads of contiguous channels.
+      t: live timesteps (bits past t-1 are zero).
+
+    Returns:
+      (G, ..., D) uint8 X': K's spikes where the OR of Q's spikes over the
+      channels of the same head, at the same token and timestep, is set.
+      Every bit is exact on both branches; the Pallas branch is the
+      ``qk_token_attention`` kernel over all leading axes as rows.
+    """
+    d = q_packed.shape[-1]
+    if use_pallas(pallas):
+        y = _qkta_pallas(q_packed.reshape(-1, d), k_packed.reshape(-1, d),
+                         heads=heads, planes=min(8, t))
+        return y.reshape(q_packed.shape)
+    return ref.qk_token_attention_ref(q_packed, k_packed, heads=heads)

@@ -74,3 +74,11 @@ def flash_attention_ref(q, k, v, *, scale: float, causal: bool = True):
         s = jnp.where(qpos >= kpos, s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bnm,bmd->bnd", p, v.astype(jnp.float32))
+
+
+def qk_token_attention_ref(q, k, *, heads: int):
+    """(..., D) uint8 packed Q, K -> (..., D) uint8 packed X': the OR of
+    each head's Q bytes, ANDed into that head's K bytes."""
+    *lead, d = q.shape
+    a = jnp.bitwise_or.reduce(q.reshape(*lead, heads, d // heads), axis=-1)
+    return k & jnp.repeat(a, d // heads, axis=-1)

@@ -8,6 +8,10 @@ is described inside a fixture (never at import: only one process may load
 the TPU library), and the persistent compilation cache is off around these
 compiles (an entry compiled for a described chip cannot be read back here).
 
+QKFormer HST-10-768's shapes are compiled too: its QKTA kernel at the
+token counts and widths of stages 1 and 2, and the 3x3 convs whose
+im2col rows (27, 864) fill no lane tile, at bucket 16.
+
 The file also pins the single interpret decision
 (``kernels.device.resolve_interpret``) and the planner rules at the
 published widths: the fused MLP kernel never runs, and the v5e-fitted
@@ -28,6 +32,7 @@ from repro.core.spikformer import fold_inference_params, init
 from repro.infer import get_backend, plan_route_tables, quantize_folded
 from repro.kernels import device, fused
 from repro.kernels.fused import tflif_lut_matmul
+from repro.kernels.qk_token_attention import qk_token_attention
 from repro.kernels.lut_matmul import RouteConstants, table_bytes
 from repro.kernels.spike_matmul import lut_gather_matmul, spike_matmul
 from repro.kernels.stdp_attention import stdp_attention
@@ -92,6 +97,30 @@ def test_spike_matmul_shift_sum_compiles_at_stem(one_chip):
         lambda x, w: spike_matmul(x, w, mode="shift_sum", interpret=False),
         ((STEM_ROWS, k), jnp.uint8), ((k, CONFIG.scs_channels[0]),
                                       jnp.float32))
+
+
+@pytest.mark.parametrize("mode,x,n", [
+    ("shift_sum", (16 * 224 * 224, 27), 96),      # QKFormer image conv
+    ("per_plane", (1, 16 * 112 * 112, 864), 192),  # SPEDS-1 conv1
+])
+def test_spike_matmul_compiles_at_qkformer_convs(one_chip, mode, x, n):
+    compile_for_chip(
+        one_chip,
+        lambda x, w: spike_matmul(x, w, mode=mode, interpret=False),
+        (x, jnp.uint8), ((x[-1], n), jnp.float32))
+
+
+@pytest.mark.parametrize("rows,d,planes", [
+    (16 * 3136, 192, 4),        # stage 1, T=4
+    (16 * 784, 384, 4),         # stage 2, T=4
+    (2 * 784, 384, 8),          # a full plane group
+])
+def test_qk_token_attention_compiles(one_chip, rows, d, planes):
+    compile_for_chip(
+        one_chip,
+        lambda q, k: qk_token_attention(q, k, heads=8, planes=planes,
+                                        interpret=False),
+        ((rows, d), jnp.uint8), ((rows, d), jnp.uint8))
 
 
 @pytest.mark.parametrize("planes,rows,chunks,n,dtype", [
