@@ -68,6 +68,19 @@ def pack_timesteps(spikes, *, time_axis: int = 0):
     return jnp.bitwise_or.reduce(x << shifts, axis=1)
 
 
+def popcount(x) -> int:
+    """Number of set bits in a uint8 array, as an exact integer.
+
+    Counts 8 bytes at a time with ``np.bitwise_count`` over a uint64 view
+    of the largest 8-byte multiple, then adds the 0-7 tail bytes. A
+    contiguous input (such as a leading-row slice of a batch) is not
+    copied."""
+    x = np.ascontiguousarray(x, np.uint8).reshape(-1)
+    n = x.size - x.size % 8
+    return (int(np.bitwise_count(x[:n].view(np.uint64)).sum())
+            + int(np.bitwise_count(x[n:]).sum()))
+
+
 def packed_occupancy(packed, t: int) -> float:
     """Mean firing rate of a ``(G, ...)`` packed spike tensor over its ``t``
     live timesteps: set bits / (t * neurons). Bits past ``t - 1`` in the
@@ -82,7 +95,7 @@ def packed_occupancy(packed, t: int) -> float:
     neurons = x.size // g if g else 0
     if not neurons:
         return 0.0
-    return float(np.unpackbits(x.reshape(-1)).sum()) / (t * neurons)
+    return popcount(x) / (t * neurons)
 
 
 def unpack_timesteps(packed, t: int, *, time_axis: int = 0,

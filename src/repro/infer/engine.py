@@ -47,6 +47,7 @@ from collections import deque
 
 import numpy as np
 
+from ..core.spike import popcount
 from ..obs.metrics import Gauge, LatencyHistogram
 from ..obs.trace import NULL_TRACER
 
@@ -181,7 +182,7 @@ def batch_occupancy(images) -> float:
     arr = np.asarray(images, np.uint8)
     if not arr.size:
         return 0.0
-    return float(np.unpackbits(arr.reshape(-1)).mean())
+    return popcount(arr) / (arr.size * 8)
 
 
 def assemble_batch(images: list, bucket: int):

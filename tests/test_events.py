@@ -187,6 +187,18 @@ def test_packed_occupancy_firing_rate():
     assert packed_occupancy(np.zeros((1, H, W, 2), np.uint8), 8) == 0.0
 
 
+@pytest.mark.parametrize("t", [4, 8, 16])
+def test_packed_occupancy_matches_unpacked_sum_exactly(t):
+    rng = np.random.default_rng(t)
+    spikes = rng.random((t, H, W, POLARITIES)) < 0.2
+    planes = pack_timesteps(spikes)
+    packed = np.asarray(planes)
+    neurons = packed.size // packed.shape[0]
+    unpacked = float(np.unpackbits(packed.reshape(-1)).sum()) / (t * neurons)
+    assert packed_occupancy(planes, t) == unpacked
+    assert packed_occupancy(planes, t) == spikes.mean()
+
+
 def test_events_to_frame_counts_and_clip():
     n = 7
     ev = EventStream(H, W, np.full(n, 4), np.full(n, 5),

@@ -35,7 +35,8 @@ from repro.infer import (ExecutionPlan, MicroBatchEngine, OccupancyRecorder,
                          chunk_occupancy, compile as infer_compile,
                          linear_layer_paths, value_chunk_occupancy)
 from repro.infer.compile import plan_chunks
-from repro.infer.engine import Request, StepAccounting, latency_summary
+from repro.infer.engine import (Request, StepAccounting, assemble_batch,
+                                latency_summary)
 from repro.kernels import lut_matmul as lut
 from repro.kernels import ops
 from repro.kernels.lut_matmul import RouteConstants
@@ -346,6 +347,47 @@ def test_batch_occupancy_counts_set_bits():
     assert batch_occupancy(np.zeros((2, 2, 2, 1), np.uint8)) == 0.0
     assert batch_occupancy(np.full((1, 1, 1, 1), 255, np.uint8)) == 1.0
     assert batch_occupancy(np.zeros((0, 2, 2, 1), np.uint8)) == 0.0
+
+
+def _unpacked_occupancy(a):
+    """The readout as it was computed before the word popcount."""
+    a = np.asarray(a, np.uint8)
+    return float(np.unpackbits(a.reshape(-1)).mean()) if a.size else 0.0
+
+
+def _occupancy_case(name):
+    rng = np.random.default_rng(1717)
+    if name == "multiple_of_8":
+        return rng.integers(0, 256, (4, 6, 8, 3), dtype=np.uint8)
+    if name == "tail":
+        return rng.integers(0, 256, (3, 5, 7, 1), dtype=np.uint8)
+    if name == "strided_view":
+        return rng.integers(0, 256, (4, 9, 7, 3), dtype=np.uint8)[:, ::2]
+    if name == "padded_prefix":
+        imgs = [rng.integers(0, 256, (6, 5, 3), dtype=np.uint8)
+                for _ in range(3)]
+        batch, pad = assemble_batch(imgs, 8)
+        assert pad == 5
+        return batch[:3]
+    if name == "sparse":
+        bits = rng.random((2, 11, 13, 3)) < 0.03
+        return np.where(bits, rng.integers(1, 256, bits.shape), 0).astype(
+            np.uint8)
+    if name == "all_ones":
+        return np.full((5, 7, 3, 1), 255, np.uint8)
+    if name == "empty":
+        return np.zeros((0, 4, 4, 3), np.uint8)
+    raise AssertionError(name)
+
+
+@pytest.mark.parametrize("case", ["multiple_of_8", "tail", "strided_view",
+                                  "padded_prefix", "sparse", "all_ones",
+                                  "empty"])
+def test_batch_occupancy_matches_unpacked_mean_exactly(case):
+    # the word popcount divides the same exact bit count by the same bit
+    # total as the unpack-and-mean it replaced, so the float is identical
+    a = _occupancy_case(case)
+    assert batch_occupancy(a) == _unpacked_occupancy(a)
 
 
 def test_engine_and_runtime_stats_report_occupancy(small):
